@@ -46,4 +46,6 @@ def main(ks=None, n_samples=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.device import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -43,4 +43,6 @@ def main(n_images_list=None, n_samples=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.device import enable_compile_cache
+    enable_compile_cache()
     main()

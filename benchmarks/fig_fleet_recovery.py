@@ -85,7 +85,7 @@ def _requests(prompts):
 
 
 def _fleet():
-    return FleetSupervisor(2, hbm_bytes=1, host_bytes=1,
+    return FleetSupervisor(2, smoke=True, hbm_bytes=1, host_bytes=1,
                            max_seq_len=MAX_SEQ_LEN, heartbeat_s=0.2,
                            miss_threshold=3, linger_s=60.0)
 
@@ -255,4 +255,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.launch.device import enable_compile_cache
+    enable_compile_cache()
     main()

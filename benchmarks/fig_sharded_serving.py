@@ -170,6 +170,8 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.device import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--worker", action="store_true")
     ap.add_argument("--devices", type=int, default=1)

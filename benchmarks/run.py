@@ -59,4 +59,6 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.device import enable_compile_cache
+    enable_compile_cache()
     main()

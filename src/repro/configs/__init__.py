@@ -1,6 +1,7 @@
 """Config registry: ``get_config(arch_id)`` / ``get_smoke_config(arch_id)``."""
 from __future__ import annotations
 
+import dataclasses
 import importlib
 
 from repro.configs.base import (
@@ -44,6 +45,15 @@ def get_smoke_config(arch_id: str) -> ModelConfig:
     return _module(arch_id).SMOKE_CONFIG
 
 
+def serving_config(arch_id: str, *, smoke: bool = False,
+                   layers: int = 0) -> ModelConfig:
+    """The config a server builds: the published one — or, with ``smoke``,
+    the reduced CPU preset — cut to ``layers`` decoder layers when
+    ``layers > 0``.  Only depth is ever cut; widths stay as published."""
+    cfg = get_smoke_config(arch_id) if smoke else get_config(arch_id)
+    return dataclasses.replace(cfg, num_layers=layers) if layers else cfg
+
+
 __all__ = [
     "ARCH_REGISTRY",
     "ASSIGNED_ARCHS",
@@ -54,4 +64,5 @@ __all__ = [
     "get_config",
     "get_smoke_config",
     "reduced",
+    "serving_config",
 ]

@@ -48,7 +48,7 @@ class PagedPrefiller:
     """Owns the jitted paged-prefill step for one engine's pool."""
 
     def __init__(self, model: Model, pool, scratch_page: int, *,
-                 backend: str = "ref", interpret: bool = True,
+                 backend: str = "ref", interpret=None,
                  bucket_min: int = 16, sharding=None, param_shardings=None):
         """``sharding``: optional
         :class:`repro.serving.sharding.ServingSharding` — the prefill jit

@@ -8,7 +8,9 @@ Two entry points:
   ``lax.scan`` over layers).
 
 Backends: ``pallas`` is the TPU kernel (runs in interpret mode off-TPU —
-correct but slow, kept for parity tests); ``ref`` is the pure-jnp oracle,
+correct but slow, kept for parity tests; ``interpret=None`` lets
+:func:`repro.kernels.platform.pallas_interpret` decide); ``ref`` is the
+pure-jnp oracle,
 which XLA compiles well on CPU/GPU.  ``auto`` picks pallas on TPU and ref
 everywhere else.  Both are lengths-bounded only up to the page-table width,
 so callers shrink ``page_table.shape[1]`` to the live maximum (the engine
@@ -28,11 +30,11 @@ from __future__ import annotations
 import functools
 
 import jax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels.paged_attn.paged_attn import paged_attention_pallas
 from repro.kernels.paged_attn.ref import paged_attention_ref
+from repro.kernels.platform import pallas_interpret, resolve_interpret
 from repro.launch.pspec import axis_divides, current_policy
 
 
@@ -40,7 +42,7 @@ def resolve_backend(backend: str = "auto") -> str:
     """'pallas' | 'ref' | 'auto' → concrete backend for this process."""
     if backend != "auto":
         return backend
-    return "pallas" if jax.default_backend() == "tpu" else "ref"
+    return "ref" if pallas_interpret() else "pallas"
 
 
 def head_shard_axis(hq: int, hkv: int):
@@ -64,7 +66,7 @@ def head_shard_axis(hq: int, hkv: int):
 def paged_attention_call(q, k_pool, v_pool, page_table, lengths, *,
                          k_scale=None, v_scale=None,
                          window: int = 0, backend: str = "ref",
-                         interpret: bool = False):
+                         interpret=None):
     """Dispatch without jit — safe to trace inside scan/jit.
 
     ``k_scale``/``v_scale`` (P, Hkv) fp32 select the int8-pool path on
@@ -75,7 +77,7 @@ def paged_attention_call(q, k_pool, v_pool, page_table, lengths, *,
                                    k_scale, v_scale, window=window)
     mesh, ax = head_shard_axis(q.shape[1], k_pool.shape[2])
     fn = functools.partial(paged_attention_pallas, window=window,
-                           interpret=interpret)
+                           interpret=resolve_interpret(interpret))
     args = (q, k_pool, v_pool, page_table, lengths)
     in_specs = (P(None, ax, None), P(None, None, ax, None),
                 P(None, None, ax, None), P(None, None), P(None))
@@ -87,9 +89,9 @@ def paged_attention_call(q, k_pool, v_pool, page_table, lengths, *,
         # per-shard pallas: heads/pages split on the TP axis, table and
         # lengths replicated; every shard computes its own softmax (heads
         # never mix), so out_specs need no reduction
-        fn = shard_map(
+        fn = jax.shard_map(
             fn, mesh=mesh, in_specs=in_specs,
-            out_specs=P(None, ax, None), check_rep=False)
+            out_specs=P(None, ax, None), check_vma=False)
     return fn(*args)
 
 
@@ -97,7 +99,7 @@ def paged_attention_call(q, k_pool, v_pool, page_table, lengths, *,
                    static_argnames=("window", "interpret", "use_ref"))
 def paged_attention(q, k_pool, v_pool, page_table, lengths, *,
                     k_scale=None, v_scale=None,
-                    window: int = 0, interpret: bool = True,
+                    window: int = 0, interpret=None,
                     use_ref: bool = False):
     return paged_attention_call(
         q, k_pool, v_pool, page_table, lengths,

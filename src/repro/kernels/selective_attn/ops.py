@@ -4,7 +4,8 @@ Accepts the model's (B, S, H, Dh) layout, pads sequences to block
 multiples (padding KV slots get INVALID_POS so they are masked out;
 padding query rows are discarded after the call), transposes to the
 kernel's (B, H, S, Dh) layout, and dispatches to Pallas — interpret mode
-on CPU (this container), compiled Mosaic on real TPUs.
+off the TPU, compiled Mosaic on it (``interpret=None`` lets
+:func:`repro.kernels.platform.pallas_interpret` decide).
 """
 from __future__ import annotations
 
@@ -12,10 +13,10 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels.paged_attn.ops import head_shard_axis
+from repro.kernels.platform import resolve_interpret
 from repro.kernels.selective_attn.ref import (
     selective_attention_paged_ref,
     selective_attention_ref,
@@ -36,11 +37,27 @@ def _pad_to(x, axis, mult, value=0):
     return jnp.pad(x, widths, constant_values=value)
 
 
+# VMEM the paged prefill kernel's per-step blocks and scratch may take: the
+# query and output blocks (double-buffered) plus the fp32 accumulator and
+# the lane-padded running max/sum, all spanning every head.  Under the
+# 16 MiB default scoped VMEM of a v5e core, with room for the page blocks.
+_PREFILL_VMEM_BUDGET = 12 * 2 ** 20
+
+
+def _prefill_block_q(sq: int, hq: int, dh: int, itemsize: int,
+                     block_q: int) -> int:
+    """Query rows per grid step: at most ``block_q``, at least 8, and few
+    enough that one step's all-heads blocks fit the VMEM budget."""
+    row_bytes = hq * (4 * dh * itemsize + 4 * dh + 2 * 128 * 4)
+    max_rows = max(8, (_PREFILL_VMEM_BUDGET // row_bytes) // 8 * 8)
+    return min(block_q, max(8, sq), max_rows)
+
+
 def selective_attention_paged_call(q, k_pool, v_pool, page_table, q_pos,
                                    lengths, *, k_scale=None, v_scale=None,
                                    window: int = 0,
                                    block_q: int = 128, backend: str = "ref",
-                                   interpret: bool = False):
+                                   interpret=None):
     """Paged selective-prefill attention — dispatch without jit.
 
     Accepts the model's (B, Sq, Hq, Dh) query layout and returns the same;
@@ -57,13 +74,13 @@ def selective_attention_paged_call(q, k_pool, v_pool, page_table, q_pos,
             qt, k_pool, v_pool, page_table, q_pos, lengths,
             k_scale, v_scale, window=window)
         return jnp.moveaxis(out, 1, 2)
-    bq = min(block_q, max(8, sq))
+    bq = _prefill_block_q(sq, hq, dh, q.dtype.itemsize, block_q)
     qt = _pad_to(qt, 2, bq)
     # padding query rows: q_pos 0 yields a garbage-but-finite row that the
     # caller slices off (their K/V never reach the pool)
     q_pos_p = _pad_to(q_pos, 1, bq, value=0)
     fn = functools.partial(selective_attention_paged_pallas, window=window,
-                           block_q=bq, interpret=interpret)
+                           block_q=bq, interpret=resolve_interpret(interpret))
     args = (qt, k_pool, v_pool, page_table, q_pos_p, lengths)
     mesh, ax = head_shard_axis(hq, k_pool.shape[2])
     in_specs = (P(None, ax, None, None), P(None, None, ax, None),
@@ -77,9 +94,9 @@ def selective_attention_paged_call(q, k_pool, v_pool, page_table, q_pos,
         # parallel across kv-head shards (see paged_attn.ops) — run it
         # per-device under shard_map instead of asking GSPMD to partition
         # the pallas call
-        fn = shard_map(
+        fn = jax.shard_map(
             fn, mesh=mesh, in_specs=in_specs,
-            out_specs=P(None, ax, None, None), check_rep=False)
+            out_specs=P(None, ax, None, None), check_vma=False)
     out = fn(*args)
     return jnp.moveaxis(out[:, :, :sq, :], 1, 2)
 
@@ -90,7 +107,7 @@ def selective_attention_paged_call(q, k_pool, v_pool, page_table, q_pos,
 def selective_attention_paged(q, k_pool, v_pool, page_table, q_pos, lengths,
                               *, k_scale=None, v_scale=None,
                               window: int = 0, block_q: int = 128,
-                              interpret: bool = True, use_ref: bool = False):
+                              interpret=None, use_ref: bool = False):
     """Standalone jit'd paged selective attention (kernel tests, ad-hoc)."""
     return selective_attention_paged_call(
         q, k_pool, v_pool, page_table, q_pos, lengths,
@@ -103,12 +120,11 @@ def selective_attention_paged(q, k_pool, v_pool, page_table, q_pos, lengths,
                                              "interpret", "use_ref"))
 def selective_attention(q, k, v, q_pos, kv_pos, *, window: int = 0,
                         block_q: int = 128, block_k: int = 128,
-                        interpret: bool = True, use_ref: bool = False):
+                        interpret=None, use_ref: bool = False):
     """q (B,Sq,Hq,Dh), k/v (B,Skv,Hkv,Dh), q_pos (B,Sq), kv_pos (B,Skv).
 
-    Returns (B, Sq, Hq, Dh).  ``interpret=True`` runs the kernel body in
-    Python on CPU (correctness path for this container); on TPU pass
-    ``interpret=False``.
+    Returns (B, Sq, Hq, Dh).  ``interpret=None`` runs the kernel compiled
+    on a TPU and in the Pallas interpreter elsewhere.
     """
     b, sq, hq, dh = q.shape
     qt = jnp.moveaxis(q, 2, 1)
@@ -128,5 +144,5 @@ def selective_attention(q, k, v, q_pos, kv_pos, *, window: int = 0,
 
     out = selective_attention_pallas(
         qt, kt, vt, q_pos_p, kv_pos_p, window=window,
-        block_q=bq, block_k=bk, interpret=interpret)
+        block_q=bq, block_k=bk, interpret=resolve_interpret(interpret))
     return jnp.moveaxis(out[:, :, :sq, :], 1, 2)

@@ -7,12 +7,14 @@ oblivious to where segments were linked — position independence lives in
 the ``q_pos``/``kv_pos`` operands, not in the loop structure.
 
 TPU mapping (DESIGN.md §3):
-  grid = (B, Hq, Sq/BQ, Skv/BK) — the KV axis is the innermost (sequential)
-  grid dim; online-softmax running stats (m, l, acc) live in VMEM scratch
-  and survive across KV steps.  Block shapes are MXU-aligned (BQ, BK, Dh
-  multiples of the 128 lane width at full scale; Dh=64 archs use the 64-lane
-  half-tile which Mosaic supports).  K is loaded as (BK, Dh) and contracted
-  with dot_general — no transposes materialize in VMEM.
+  dense:  grid = (B, Hq, Sq/BQ, Skv/BK);  paged: grid = (B, Sq/BQ, pages),
+  one whole page (all kv heads) per step with the heads looped inside.
+  The KV axis is the innermost (sequential) grid dim; online-softmax
+  running stats (m, l, acc) live in VMEM scratch and survive across KV
+  steps.  Every block's last two dims are whole or (8, 128)-aligned, as
+  Mosaic requires: positions ride as (BQ, 1) columns / (1, BK) rows, pages
+  keep the pool's (Hkv, Dh).  K is loaded as (BK, Dh) and contracted with
+  dot_general — no transposes materialize in VMEM.
 """
 from __future__ import annotations
 
@@ -27,7 +29,7 @@ INVALID_POS = jnp.iinfo(jnp.int32).max
 NEG_INF = -1e30
 
 
-def _sel_attn_kernel(q_pos_ref, kv_pos_ref,        # prefetch-ish operands
+def _sel_attn_kernel(q_pos_ref, kv_pos_ref,        # (1,BQ,1) / (1,1,BK)
                      q_ref, k_ref, v_ref,          # blocks
                      o_ref,                        # output block
                      m_ref, l_ref, acc_ref,        # VMEM scratch
@@ -43,15 +45,15 @@ def _sel_attn_kernel(q_pos_ref, kv_pos_ref,        # prefetch-ish operands
     q = q_ref[0, 0].astype(jnp.float32)            # (BQ, Dh)
     k = k_ref[0, 0].astype(jnp.float32)            # (BK, Dh)
     v = v_ref[0, 0].astype(jnp.float32)            # (BK, Dh)
-    qp = q_pos_ref[0]                              # (BQ,)
-    kp = kv_pos_ref[0]                             # (BK,)
+    qp = q_pos_ref[0]                              # (BQ, 1) column
+    kp = kv_pos_ref[0]                             # (1, BK) row
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
 
-    valid = (kp[None, :] != INVALID_POS) & (kp[None, :] <= qp[:, None])
+    valid = (kp != INVALID_POS) & (kp <= qp)
     if window > 0:
-        valid &= kp[None, :] > qp[:, None] - window
+        valid &= kp > qp - window
     s = jnp.where(valid, s, NEG_INF)
 
     m_prev = m_ref[...]                            # (BQ, 1)
@@ -75,14 +77,24 @@ def _sel_attn_kernel(q_pos_ref, kv_pos_ref,        # prefetch-ish operands
 
 
 def _sel_attn_paged_kernel(page_table_ref, lengths_ref,   # scalar prefetch
-                           q_pos_ref,                     # (1, BQ) operand
+                           q_pos_ref,                     # (1, BQ, 1)
                            q_ref, k_ref, v_ref,           # VMEM blocks
-                           o_ref,
-                           m_ref, l_ref, acc_ref,         # VMEM scratch
-                           *, page_size: int, n_pages: int, window: int,
-                           scale: float):
+                           *rest, page_size: int, n_pages: int, window: int,
+                           scale: float, group: int, quantized: bool):
+    """One grid step = one query block of one sequence against one whole
+    page, all heads.  Blocks keep the pool's last two dims
+    (``(1, page_size, Hkv, Dh)``, as Mosaic requires); heads are a static
+    loop over static strided loads of each kv head's ``(page_size, Dh)``
+    slice.  On an int8 pool (``quantized``) the page's ``(1, 1, Hkv)``
+    fp32 scale rows arrive beside it: the K scale folds into the logits
+    scale, the V scale multiplies the page's accumulator contribution —
+    dequantization never leaves the registers."""
+    if quantized:
+        ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
+    else:
+        o_ref, m_ref, l_ref, acc_ref = rest
     b = pl.program_id(0)
-    j = pl.program_id(3)
+    j = pl.program_id(2)
 
     @pl.when(j == 0)
     def _init():
@@ -90,95 +102,45 @@ def _sel_attn_paged_kernel(page_table_ref, lengths_ref,   # scalar prefetch
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, 0].astype(jnp.float32)                # (BQ, Dh)
-    k = k_ref[0, :, 0].astype(jnp.float32)             # (page_size, Dh)
-    v = v_ref[0, :, 0].astype(jnp.float32)
-    qp = q_pos_ref[0]                                  # (BQ,)
+    qp = q_pos_ref[0]                                  # (BQ, 1) column
     length = lengths_ref[b]
-
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-
     # paged-prefill invariant: cache slot i holds original position i, so
     # the kv position of this page's slots is their token index
     tok = j * page_size + jax.lax.broadcasted_iota(
         jnp.int32, (1, page_size), 1)                  # (1, page_size)
-    valid = (tok < length) & (tok <= qp[:, None])
+    valid = (tok < length) & (tok <= qp)
     if window > 0:
-        valid &= tok > qp[:, None] - window
-    s = jnp.where(valid, s, NEG_INF)
+        valid &= tok > qp - window
 
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
-    l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    m_ref[...] = m_new
+    for kh in range(k_ref.shape[2]):
+        k = k_ref[0, :, kh, :].astype(jnp.float32)     # (page_size, Dh)
+        v = v_ref[0, :, kh, :].astype(jnp.float32)
+        for h in range(kh * group, (kh + 1) * group):
+            q = q_ref[0, h].astype(jnp.float32)        # (BQ, Dh)
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            s = s * scale
+            if quantized:
+                s = s * ks_ref[0, :, kh:kh + 1]
+            s = jnp.where(valid, s, NEG_INF)
+
+            m_prev = m_ref[h]                          # (BQ, 1)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+            l_ref[h] = alpha * l_ref[h] + jnp.sum(p, axis=-1, keepdims=True)
+            pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+            if quantized:
+                pv = pv * vs_ref[0, :, kh:kh + 1]
+            acc_ref[h] = acc_ref[h] * alpha + pv
+            m_ref[h] = m_new
 
     @pl.when(j == n_pages - 1)
     def _finish():
         l = l_ref[...]
-        o_ref[0, 0, ...] = (acc_ref[...] /
-                            jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
-
-
-def _sel_attn_paged_q8_kernel(page_table_ref, lengths_ref,  # scalar prefetch
-                              q_pos_ref,                    # (1, BQ) operand
-                              q_ref, k_ref, v_ref,          # VMEM blocks
-                              ks_ref, vs_ref,               # (1,1) page scales
-                              o_ref,
-                              m_ref, l_ref, acc_ref,        # VMEM scratch
-                              *, page_size: int, n_pages: int, window: int,
-                              scale: float):
-    """Int8-pool variant of :func:`_sel_attn_paged_kernel`: pages arrive as
-    int8 with one fp32 scale per (page, kv head) prefetched through the
-    same page table.  The K scale folds into the softmax scale; the V
-    scale multiplies this page's accumulator contribution — dequantization
-    never leaves the registers."""
-    b = pl.program_id(0)
-    j = pl.program_id(3)
-
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    q = q_ref[0, 0].astype(jnp.float32)                # (BQ, Dh)
-    k = k_ref[0, :, 0].astype(jnp.float32)             # (page_size, Dh) int8→f32
-    v = v_ref[0, :, 0].astype(jnp.float32)
-    ks = ks_ref[0, 0]
-    vs = vs_ref[0, 0]
-    qp = q_pos_ref[0]                                  # (BQ,)
-    length = lengths_ref[b]
-
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * (scale * ks)
-
-    tok = j * page_size + jax.lax.broadcasted_iota(
-        jnp.int32, (1, page_size), 1)                  # (1, page_size)
-    valid = (tok < length) & (tok <= qp[:, None])
-    if window > 0:
-        valid &= tok > qp[:, None] - window
-    s = jnp.where(valid, s, NEG_INF)
-
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
-    l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32) * vs
-    m_ref[...] = m_new
-
-    @pl.when(j == n_pages - 1)
-    def _finish():
-        l = l_ref[...]
-        o_ref[0, 0, ...] = (acc_ref[...] /
-                            jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] /
+                    jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
 
 
 def selective_attention_paged_pallas(q, k_pool, v_pool, page_table, q_pos,
@@ -201,40 +163,38 @@ def selective_attention_paged_pallas(q, k_pool, v_pool, page_table, q_pos,
     n_pages = page_table.shape[1]
     assert sq % block_q == 0
     group = hq // hkv
-    grid = (b, hq, sq // block_q, n_pages)
     quantized = k_scale is not None
 
     kernel = functools.partial(
-        _sel_attn_paged_q8_kernel if quantized else _sel_attn_paged_kernel,
-        page_size=page_size, n_pages=n_pages,
-        window=window, scale=1.0 / (dh ** 0.5))
+        _sel_attn_paged_kernel, page_size=page_size, n_pages=n_pages,
+        window=window, scale=1.0 / (dh ** 0.5), group=group,
+        quantized=quantized)
 
+    page_spec = pl.BlockSpec((1, page_size, hkv, dh),
+                             lambda b_, i, j, pt, ln: (pt[b_, j], 0, 0, 0))
     in_specs = [
-        pl.BlockSpec((1, block_q),
-                     lambda b_, h, i, j, pt, ln: (b_, i)),         # q_pos
-        pl.BlockSpec((1, 1, block_q, dh),
-                     lambda b_, h, i, j, pt, ln: (b_, h, i, 0)),   # q
-        pl.BlockSpec((1, page_size, 1, dh),
-                     lambda b_, h, i, j, pt, ln: (pt[b_, j], 0, h // group, 0)),
-        pl.BlockSpec((1, page_size, 1, dh),
-                     lambda b_, h, i, j, pt, ln: (pt[b_, j], 0, h // group, 0)),
+        pl.BlockSpec((1, block_q, 1),
+                     lambda b_, i, j, pt, ln: (b_, i, 0)),          # q_pos
+        pl.BlockSpec((1, hq, block_q, dh),
+                     lambda b_, i, j, pt, ln: (b_, 0, i, 0)),       # q
+        page_spec, page_spec,
     ]
-    args = [page_table, lengths, q_pos, q, k_pool, v_pool]
+    args = [page_table, lengths, q_pos.reshape(b, sq, 1), q, k_pool, v_pool]
     if quantized:
         in_specs += [pl.BlockSpec(
-            (1, 1), lambda b_, h, i, j, pt, ln: (pt[b_, j], h // group))] * 2
-        args += [k_scale, v_scale]
+            (1, 1, hkv), lambda b_, i, j, pt, ln: (pt[b_, j], 0, 0))] * 2
+        args += [k_scale.reshape(p, 1, hkv), v_scale.reshape(p, 1, hkv)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,          # page_table, lengths
-        grid=grid,
+        grid=(b, sq // block_q, n_pages),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, block_q, dh),
-                               lambda b_, h, i, j, pt, ln: (b_, h, i, 0)),
+        out_specs=pl.BlockSpec((1, hq, block_q, dh),
+                               lambda b_, i, j, pt, ln: (b_, 0, i, 0)),
         scratch_shapes=[
-            pltpu.VMEM((block_q, 1), jnp.float32),   # m (running max)
-            pltpu.VMEM((block_q, 1), jnp.float32),   # l (running sum)
-            pltpu.VMEM((block_q, dh), jnp.float32),  # acc
+            pltpu.VMEM((hq, block_q, 1), jnp.float32),   # m (running max)
+            pltpu.VMEM((hq, block_q, 1), jnp.float32),   # l (running sum)
+            pltpu.VMEM((hq, block_q, dh), jnp.float32),  # acc
         ],
     )
 
@@ -265,12 +225,14 @@ def selective_attention_pallas(q, k, v, q_pos, kv_pos, *, window: int = 0,
         _sel_attn_kernel, window=window, n_kv_blocks=n_kv,
         scale=1.0 / (dh ** 0.5))
 
+    # positions get a unit axis so every block's last two dims are legal
+    # for Mosaic: q_pos a (BQ, 1) column, kv_pos a (1, BK) row
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, block_q), lambda b_, h, i, j: (b_, i)),          # q_pos
-            pl.BlockSpec((1, block_k), lambda b_, h, i, j: (b_, j)),          # kv_pos
+            pl.BlockSpec((1, block_q, 1), lambda b_, h, i, j: (b_, i, 0)),
+            pl.BlockSpec((1, 1, block_k), lambda b_, h, i, j: (b_, 0, j)),
             pl.BlockSpec((1, 1, block_q, dh), lambda b_, h, i, j: (b_, h, i, 0)),
             pl.BlockSpec((1, 1, block_k, dh),
                          lambda b_, h, i, j: (b_, h // group, j, 0)),
@@ -286,4 +248,4 @@ def selective_attention_pallas(q, k, v, q_pos, kv_pos, *, window: int = 0,
             pltpu.VMEM((block_q, dh), jnp.float32),  # acc
         ],
         interpret=interpret,
-    )(q_pos, kv_pos, q, k, v)
+    )(q_pos.reshape(b, sq, 1), kv_pos.reshape(b, 1, skv), q, k, v)

@@ -64,6 +64,7 @@ import signal
 import socket
 import subprocess
 import sys
+import tempfile
 import threading
 import time
 import urllib.error
@@ -381,13 +382,19 @@ def host_main(args) -> int:
     from repro.cache.library import KVLibrary
     from repro.cache.net import (KVPeerServer, PeerTransport,
                                  ReusableThreadingHTTPServer)
-    from repro.configs import get_smoke_config
+    from repro.configs import serving_config
+    from repro.launch.device import require_platform
     from repro.models import build_model
     from repro.serving import EngineConfig, MPICEngine
+    from repro.serving.sharding import init_params
 
-    cfg = get_smoke_config(args.arch)
+    # the supervisor spawns one host per chip and says which platform it
+    # must get: a host that finds its chip taken must die here, loudly,
+    # not serve on the CPU JAX falls back to
+    require_platform(args.platform or None)
+    cfg = serving_config(args.arch, smoke=args.smoke, layers=args.layers)
     model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
+    params = init_params(model, jax.random.PRNGKey(0))
     lib_kw = {}
     if args.hbm_bytes > 0:
         lib_kw["hbm_capacity"] = args.hbm_bytes
@@ -543,13 +550,32 @@ class FleetSupervisor:
                  max_seq_len: int = 256, peer_timeout_s: float = 0.5,
                  linger_s: float = 20.0, hbm_bytes: int = 0,
                  host_bytes: int = 0, start_grace_s: float = 180.0,
-                 freeze_idle_s: float = 0.0,
-                 env: Optional[dict] = None):
+                 freeze_idle_s: float = 0.0, smoke: bool = False,
+                 layers: int = 0, env: Optional[dict] = None):
+        """``smoke``/``layers`` pick each host's model exactly as
+        ``serve --smoke --layers`` does (:func:`repro.configs
+        .serving_config`).  On a TPU machine every host needs a chip of
+        its own: more hosts than attached chips is refused here, before
+        anything is spawned."""
+        from repro.launch.device import attached_tpu_chips, expected_platform
         from repro.serving.router import make_router
         assert hosts >= 1
+        self._env = env
+        self.platform = expected_platform(
+            os.environ if env is None else env)
+        if self.platform == "tpu":
+            chips = attached_tpu_chips()
+            if hosts > chips:
+                raise ValueError(
+                    f"a fleet of {hosts} engine hosts needs {hosts} TPU "
+                    f"chips (one process per chip), but {chips} are "
+                    f"attached; use --fleet {max(chips, 1)} or fewer, or "
+                    f"--replicas for in-process replicas")
         self.arch = arch
+        self.smoke = smoke
+        self.layers = layers
         self.base_dir = base_dir or os.path.join(
-            "/tmp", f"mpic_fleet_{os.getpid()}")
+            tempfile.gettempdir(), f"mpic_fleet_{os.getpid()}")
         os.makedirs(self.base_dir, exist_ok=True)
         self.router = make_router(router)
         self.router_name = router
@@ -564,7 +590,6 @@ class FleetSupervisor:
         self.host_bytes = host_bytes
         self.start_grace_s = start_grace_s
         self.freeze_idle_s = freeze_idle_s
-        self._env = env
         self.hosts: List[FleetHost] = []
         for i in range(hosts):
             spool = os.path.join(self.base_dir, f"host{i}", "spool")
@@ -596,7 +621,11 @@ class FleetSupervisor:
                "--linger-s", str(self.linger_s),
                "--hbm-bytes", str(self.hbm_bytes),
                "--host-bytes", str(self.host_bytes),
-               "--freeze-idle-s", str(self.freeze_idle_s)]
+               "--freeze-idle-s", str(self.freeze_idle_s),
+               "--layers", str(self.layers),
+               "--platform", self.platform or ""]
+        if self.smoke:
+            cmd.append("--smoke")
         env = dict(os.environ if self._env is None else self._env)
         src = os.path.dirname(os.path.dirname(os.path.dirname(
             os.path.abspath(__file__))))
@@ -627,6 +656,13 @@ class FleetSupervisor:
             for h in self.hosts:
                 if h.spec.host_id not in want or h.spec.host_id in ok:
                     continue
+                if h.proc is not None and h.proc.poll() is not None:
+                    # died while starting (e.g. its chip was taken): no
+                    # heartbeat will ever come, so say why now
+                    raise RuntimeError(
+                        f"host {h.spec.host_id} exited with "
+                        f"{h.proc.returncode} before it was healthy:\n"
+                        f"{self._log_tail(h)}")
                 hb = self._http("GET", h, "/health", timeout=1.0)
                 if hb is not None:
                     h.health, h.misses = hb, 0
@@ -638,6 +674,14 @@ class FleetSupervisor:
         raise TimeoutError(
             f"hosts {sorted(want)} not healthy after {timeout_s}s "
             f"(states: {[(h.spec.host_id, h.state) for h in self.hosts]})")
+
+    def _log_tail(self, host: FleetHost, n: int = 2000) -> str:
+        path = os.path.join(self.base_dir, f"host{host.spec.host_id}.log")
+        try:
+            with open(path, "rb") as f:
+                return f.read()[-n:].decode(errors="replace")
+        except OSError:
+            return f"(no log at {path})"
 
     def _host(self, host_id: int) -> FleetHost:
         return self.hosts[host_id]
@@ -998,18 +1042,20 @@ def run_fleet(*, hosts: int = 2, requests: int = 8,
               max_new_tokens: int = 8, mpic_k: int = 8,
               router: str = "affinity",
               deadline_s: Optional[float] = None,
-              media_len: int = 24, timeout_s: float = 300.0,
-              freeze_idle_s: float = 0.0) -> dict:
+              timeout_s: float = 300.0, freeze_idle_s: float = 0.0,
+              smoke: bool = False, layers: int = 0) -> dict:
     """End-to-end fleet demo: spawn hosts, upload media, serve a synthetic
     request wave cross-process, drain, and return the report (used by
     ``serve.py --fleet N`` and the CLI below)."""
-    from repro.configs import get_smoke_config
+    from repro.configs import serving_config
     from repro.data import image_embeds, make_dialogues
     from repro.serving.request import Request
 
-    cfg = get_smoke_config(arch)
+    cfg = serving_config(arch, smoke=smoke, layers=layers)
+    media_len = cfg.media_token_len
     fleet = FleetSupervisor(hosts, arch=arch, router=router,
-                            freeze_idle_s=freeze_idle_s)
+                            freeze_idle_s=freeze_idle_s, smoke=smoke,
+                            layers=layers)
     try:
         print(f"starting {hosts} engine host(s)…", flush=True)
         fleet.start()
@@ -1059,9 +1105,18 @@ def main() -> int:
     # host-mode args
     ap.add_argument("--host-id", type=int, default=0)
     ap.add_argument("--arch", default="llava-1.6-7b")
+    ap.add_argument("--smoke", action="store_true",
+                    help="the reduced CPU preset of --arch instead of its "
+                         "published widths")
+    ap.add_argument("--layers", type=int, default=0,
+                    help=">0: cut the model to this many decoder layers")
+    ap.add_argument("--platform", default="",
+                    help="internal: the platform a host must initialise "
+                         "JAX on (set by the supervisor)")
     ap.add_argument("--ctrl-port", type=int, default=0)
     ap.add_argument("--block-port", type=int, default=0)
-    ap.add_argument("--spool-dir", default="/tmp/mpic_fleet_host/spool")
+    ap.add_argument("--spool-dir", default=os.path.join(
+        tempfile.gettempdir(), "mpic_fleet_host", "spool"))
     ap.add_argument("--peers", default="",
                     help="comma-separated host:port peer BLOCK servers")
     ap.add_argument("--slots", type=int, default=2)
@@ -1091,11 +1146,12 @@ def main() -> int:
     args = ap.parse_args()
     if args.serve_host:
         return host_main(args)
-    run_fleet(hosts=args.hosts, requests=args.requests, arch=args.arch,
-              policy=args.policy, max_new_tokens=args.max_new_tokens,
-              mpic_k=args.mpic_k, router=args.router,
-              deadline_s=args.deadline_s)
-    return 0
+    rep = run_fleet(hosts=args.hosts, requests=args.requests,
+                    arch=args.arch, policy=args.policy,
+                    max_new_tokens=args.max_new_tokens, mpic_k=args.mpic_k,
+                    router=args.router, deadline_s=args.deadline_s,
+                    smoke=args.smoke, layers=args.layers)
+    return 1 if rep["failed"] else 0
 
 
 if __name__ == "__main__":

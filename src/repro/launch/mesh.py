@@ -7,12 +7,20 @@ device state (smoke tests must keep seeing 1 CPU device).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``: the model's logical
+    ``shard()`` constraints and GSPMD propagation assume it (``Explicit``
+    axes, the default of ``make_mesh`` in this JAX, reject both)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def batch_axes(multi_pod: bool):
@@ -29,7 +37,7 @@ def make_serving_mesh(*, data: int = 1, model: int = 0):
     """
     n = len(jax.devices())
     model = model or max(n // max(data, 1), 1)
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
 
 
 def serving_rules() -> dict:
@@ -58,9 +66,3 @@ def activation_rules(*, multi_pod: bool = False, shard_kv_seq: bool = False,
         "experts": "model",
         "vocab": "model",
     }
-
-
-# hardware constants (TPU v5e) for the roofline terms
-PEAK_FLOPS_BF16 = 197e12        # per chip
-HBM_BW = 819e9                  # bytes/s per chip
-ICI_BW = 50e9                   # bytes/s per link

@@ -2,9 +2,13 @@
 
 Boots an MPIC engine — or, with ``--replicas N``, a data-parallel
 :class:`~repro.serving.cluster.MPICCluster` — for the chosen architecture
-(reduced config on CPU), feeds it a synthetic multimodal request stream,
-and prints the TTFT / throughput report.  The production-mesh variant of
-the same step functions is what launch/dryrun.py lowers.
+at its published widths (``--layers N`` cuts only its depth, as one chip
+forces; ``--smoke`` takes the reduced CPU preset instead), feeds it a
+synthetic 2-image MMDU-style request stream with the config's own media
+length, and prints the TTFT / throughput report.  The run exits non-zero
+if any request failed or expired.  On a TPU machine the process checks
+that JAX really got the chip (it falls back to the CPU in silence when
+another process holds it).
 
 Every engine knob is drivable from the CLI: ``--no-paged`` /
 ``--no-pipelined`` select the dense / sequential baselines,
@@ -22,16 +26,27 @@ docs/ARCHITECTURE.md, "network tier").
 ``--policy`` takes a comma-separated trace cycled over the request stream
 (e.g. ``--policy mpic,full_recompute``).  An unknown policy name in the
 trace fails *that request* with a per-request error and the server keeps
-serving — it does not hard-exit the run.
+serving the others; the exit code then reports the failure.
+
+:func:`build_server` and :func:`submit_dialogues` are the two halves of a
+run that ``chip_smoke.py`` drives directly.
 """
 from __future__ import annotations
 
 import argparse
+import dataclasses
+import sys
+from typing import List, Optional
 
 import jax
 
-from repro.configs import get_smoke_config
+from repro.configs import serving_config
 from repro.data import image_embeds, make_dialogues
+from repro.launch.device import (
+    enable_compile_cache,
+    expected_platform,
+    require_platform,
+)
 from repro.models import build_model
 from repro.serving import (
     ClusterConfig,
@@ -40,6 +55,7 @@ from repro.serving import (
     MPICEngine,
     Request,
 )
+from repro.serving.sharding import init_params
 
 
 def parse_mesh(spec: str):
@@ -53,9 +69,15 @@ def parse_mesh(spec: str):
     return make_serving_mesh(data=data, model=model)
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llava-1.6-7b")
+    ap.add_argument("--smoke", action="store_true",
+                    help="the reduced CPU preset of --arch (<=2 layers, "
+                         "d_model<=256) instead of its published widths")
+    ap.add_argument("--layers", type=int, default=0,
+                    help=">0: cut the model to this many decoder layers "
+                         "(depth only; widths stay as published)")
     ap.add_argument("--requests", type=int, default=4)
     ap.add_argument("--policy", default="mpic",
                     help="policy per request, comma-separated trace cycled "
@@ -65,7 +87,9 @@ def main():
     ap.add_argument("--mpic-k", type=int, default=8)
     ap.add_argument("--max-new-tokens", type=int, default=8)
     ap.add_argument("--slots", type=int, default=4)
-    ap.add_argument("--max-seq-len", type=int, default=512)
+    ap.add_argument("--max-seq-len", type=int, default=0,
+                    help="kv tokens per slot; 0 = the longest prompt plus "
+                         "--max-new-tokens, rounded up to 256")
     ap.add_argument("--paged", dest="paged", action="store_true",
                     default=True, help="pool-backed decode path (default)")
     ap.add_argument("--no-paged", dest="paged", action="store_false",
@@ -90,8 +114,9 @@ def main():
     ap.add_argument("--fleet", type=int, default=0,
                     help=">0: serve through a supervised MULTI-PROCESS "
                          "fleet of N engine hosts (one process + spool "
-                         "dir + peer block server each) behind the "
-                         "heartbeat router — see launch/fleet.py")
+                         "dir + peer block server each, one chip each on "
+                         "a TPU machine) behind the heartbeat router — "
+                         "see launch/fleet.py")
     ap.add_argument("--router", default="affinity",
                     choices=["random", "least_loaded", "affinity"],
                     help="cluster routing policy (with --replicas > 1)")
@@ -115,31 +140,45 @@ def main():
                          "target=replica0,start=5,stop=6'")
     ap.add_argument("--fault-seed", type=int, default=0,
                     help="seed for fault-plan probability draws")
-    args = ap.parse_args()
-    if args.fleet > 0:
-        # multi-process path: the supervisor owns model building, uploads
-        # and the request wave — every other engine knob that matters
-        # cross-process is forwarded, the rest are in-process only
-        from repro.launch.fleet import run_fleet
-        run_fleet(hosts=args.fleet, requests=args.requests,
-                  arch=args.arch, policy=args.policy,
-                  max_new_tokens=args.max_new_tokens,
-                  mpic_k=args.mpic_k, router=args.router,
-                  deadline_s=args.deadline_s,
-                  freeze_idle_s=args.freeze_idle_s)
-        return
+    return ap
+
+
+@dataclasses.dataclass
+class Server:
+    """What :func:`build_server` assembled for one run."""
+    cfg: object                     # the ModelConfig served
+    engine: object                  # MPICEngine or MPICCluster
+    mesh: object = None
+    dialogues: list = None
+    peer_server: object = None
+    faults: object = None
+
+
+def build_server(args) -> Server:
+    """Model, parameters and engine (or replica cluster) exactly as
+    ``serve`` runs them.  Parameters are initialised straight onto the
+    mesh's shardings (or the default device), from ``PRNGKey(0)``."""
     peers = [p.strip() for p in args.peers.split(",") if p.strip()]
     faults = None
     if args.fault_plan:
         from repro.cache.faults import FaultPlan
         faults = FaultPlan.parse(args.fault_plan, seed=args.fault_seed)
 
-    cfg = get_smoke_config(args.arch)
+    cfg = serving_config(args.arch, smoke=args.smoke, layers=args.layers)
+    dialogues = make_dialogues(n=args.requests, n_images=2,
+                               d_model=cfg.d_model,
+                               media_len=cfg.media_token_len,
+                               style="mmdu", user_id="u1")
+    max_seq_len = args.max_seq_len
+    if max_seq_len <= 0:
+        need = max(d.prompt.total_len for d in dialogues) \
+            + args.max_new_tokens + 1
+        max_seq_len = -(-need // 256) * 256
     model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
     mesh = parse_mesh(args.mesh)
+    params = init_params(model, jax.random.PRNGKey(0), mesh)
     engine_cfg = EngineConfig(
-        max_seq_len=args.max_seq_len, decode_slots=args.slots,
+        max_seq_len=max_seq_len, decode_slots=args.slots,
         paged=args.paged, pipelined=args.pipelined,
         prefill_chunk_tokens=args.prefill_chunk,
         freeze_idle_s=args.freeze_idle_s)
@@ -165,34 +204,63 @@ def main():
             from repro.cache.net import KVPeerServer
             peer_server = KVPeerServer(eng.static_lib,
                                        port=args.serve_blocks)
-    if peer_server is not None:
-        print(f"serving KV blocks to peers at {peer_server.address}")
+    return Server(cfg, eng, mesh, dialogues, peer_server, faults)
 
-    dialogues = make_dialogues(n=args.requests, n_images=2,
-                               d_model=cfg.d_model, media_len=24,
-                               style="mmdu", user_id="u1")
+
+def submit_dialogues(server: Server, args) -> List[Request]:
+    """Upload every image of the run's dialogues (precompute its KV into
+    the library), then submit one request per dialogue, cycling the
+    ``--policy`` trace.  Returns the submitted requests."""
+    cfg, eng = server.cfg, server.engine
     seen = set()
-    for d in dialogues:
+    for d in server.dialogues:
         for mid in d.media_ids:
             if mid not in seen:
-                eng.upload("u1", mid, image_embeds(mid, 24, cfg.d_model))
+                eng.upload("u1", mid, image_embeds(mid, cfg.media_token_len,
+                                                   cfg.d_model))
                 seen.add(mid)
-
     policies = [p.strip() for p in args.policy.split(",") if p.strip()]
-    if not policies:
-        ap.error("--policy needs at least one policy name")
-    for i, d in enumerate(dialogues):
+    reqs = []
+    for i, d in enumerate(server.dialogues):
         policy = policies[i % len(policies)]
         kw = {"k": args.mpic_k} if policy == "mpic" else {}
-        eng.submit(Request(prompt=d.prompt,
-                           max_new_tokens=args.max_new_tokens,
-                           policy=policy, policy_kwargs=kw,
-                           deadline_s=args.deadline_s))
+        reqs.append(eng.submit(Request(prompt=d.prompt,
+                                       max_new_tokens=args.max_new_tokens,
+                                       policy=policy, policy_kwargs=kw,
+                                       deadline_s=args.deadline_s)))
+    return reqs
+
+
+def main(argv: Optional[List[str]] = None) -> int:
+    ap = build_parser()
+    args = ap.parse_args(argv)
+    if not [p for p in args.policy.split(",") if p.strip()]:
+        ap.error("--policy needs at least one policy name")
+    enable_compile_cache()
+    if args.fleet > 0:
+        # multi-process path: the supervisor owns the request wave and
+        # never touches JAX itself (each host process holds its own chip)
+        from repro.launch.fleet import run_fleet
+        rep = run_fleet(hosts=args.fleet, requests=args.requests,
+                        arch=args.arch, policy=args.policy,
+                        max_new_tokens=args.max_new_tokens,
+                        mpic_k=args.mpic_k, router=args.router,
+                        deadline_s=args.deadline_s,
+                        freeze_idle_s=args.freeze_idle_s,
+                        smoke=args.smoke, layers=args.layers)
+        return 1 if rep["failed"] else 0
+    info = require_platform(expected_platform())
+    server = build_server(args)
+    eng, cfg, mesh = server.engine, server.cfg, server.mesh
+    if server.peer_server is not None:
+        print(f"serving KV blocks to peers at {server.peer_server.address}")
+    submit_dialogues(server, args)
     done = eng.run()
     mesh_desc = "x".join(str(s) for s in mesh.devices.shape) if mesh \
         else "unsharded"
-    print(f"\narch={cfg.name} policy={args.policy} paged={args.paged} "
-          f"pipelined={args.pipelined} mesh={mesh_desc} "
+    print(f"\narch={cfg.name} layers={cfg.num_layers} "
+          f"device={info['kind']}x{info['count']} policy={args.policy} "
+          f"paged={args.paged} pipelined={args.pipelined} mesh={mesh_desc} "
           f"replicas={args.replicas}"
           + (f" router={args.router}" if args.replicas > 1 else ""))
     for r in done:
@@ -204,11 +272,13 @@ def main():
         print(f"  {r.req_id}: FAILED — {r.error}")
     for r in eng.expired:
         print(f"  {r.req_id}: DEADLINE — {r.error}")
-    if faults is not None:
-        print(f"  fault_plan: {faults.stats()}")
+    if server.faults is not None:
+        print(f"  fault_plan: {server.faults.stats()}")
     for k, v in eng.report().items():
         print(f"  {k}: {v}")
+    return 1 if (eng.failed or eng.expired
+                 or len(done) < len(server.dialogues)) else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

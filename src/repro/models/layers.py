@@ -26,9 +26,11 @@ def _dtype(name: str):
 
 
 def dense_init(key, shape, dtype, scale: Optional[float] = None):
+    """Normal(0, scale²) weights drawn in ``dtype`` itself: a bf16 model
+    never holds an fp32 copy of a weight, even transiently."""
     fan_in = shape[0]
     scale = scale if scale is not None else 1.0 / math.sqrt(fan_in)
-    return (jax.random.normal(key, shape, jnp.float32) * scale).astype(dtype)
+    return (jax.random.normal(key, shape, dtype) * scale).astype(dtype)
 
 
 # ---------------------------------------------------------------------------

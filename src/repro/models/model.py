@@ -156,7 +156,7 @@ class Model:
                                 v_scales=None, *,
                                 media_embeds=None, media_mask=None,
                                 backend: str = "ref",
-                                interpret: bool = False):
+                                interpret=None):
         """MPIC selective prefill against the shared paged KV pool.
 
         See :func:`repro.models.transformer.selective_prefill_paged` for
@@ -193,7 +193,7 @@ class Model:
     def decode_step_paged(self, params, token, position, pool_k, pool_v,
                           page_table, lengths, write_pages, write_offs,
                           k_scales=None, v_scales=None, *,
-                          backend: str = "ref", interpret: bool = False):
+                          backend: str = "ref", interpret=None):
         """One decode step against the shared paged KV pool (all slots).
 
         See :func:`repro.models.transformer.decode_paged` for shapes.

@@ -362,7 +362,7 @@ def decode_paged(params: dict, cfg, embeds: jnp.ndarray,
                  pool_v: jnp.ndarray, page_table: jnp.ndarray,
                  lengths: jnp.ndarray, write_pages: jnp.ndarray,
                  write_offs: jnp.ndarray, k_scales=None, v_scales=None,
-                 *, backend: str = "ref", interpret: bool = False):
+                 *, backend: str = "ref", interpret=None):
     """One decode step for ALL slots against the shared paged KV pool.
 
     embeds      (B, 1, D)       new-token embeddings
@@ -448,7 +448,7 @@ def selective_prefill_paged(params: dict, cfg, embeds: jnp.ndarray,
                             lengths: jnp.ndarray, write_pages: jnp.ndarray,
                             write_offs: jnp.ndarray, k_scales=None,
                             v_scales=None, *, backend: str = "ref",
-                            interpret: bool = False):
+                            interpret=None):
     """MPIC selective-attention prefill straight against the paged KV pool.
 
     embeds       (B, Sq, D)      embeddings of the selected tokens (padded

@@ -28,7 +28,6 @@ import dataclasses
 import re
 from typing import Dict, List, Optional
 
-from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -121,6 +120,38 @@ def collective_bytes(hlo_text: str, trip_counts: List[int]) -> CollectiveStats:
     return CollectiveStats(total, by_kind, count)
 
 
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    """Published per-chip peaks of one accelerator generation."""
+    flops_bf16: float           # FLOP/s
+    hbm_bw: float               # bytes/s
+    ici_bw: float               # bytes/s per interconnect link
+    source: str
+
+
+# Keyed by ``jax.Device.device_kind``.  A device that is not here has no
+# roofline: :func:`peaks` raises rather than borrowing another chip's.
+PEAKS: Dict[str, ChipPeaks] = {
+    "TPU v5 lite": ChipPeaks(
+        flops_bf16=197e12, hbm_bw=819e9,
+        # 1,600 Gbit/s of chip-to-chip interconnect over 4 links
+        ici_bw=50e9,
+        source='Google Cloud documentation, "TPU v5e" (system '
+               'architecture: per-chip peak compute, HBM, ICI)'),
+}
+
+
+def peaks(device_kind: str) -> ChipPeaks:
+    """Peaks for a device kind (``jax.devices()[0].device_kind``)."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r} "
+            f"(known: {sorted(PEAKS)}); add them to "
+            f"repro.roofline.analysis.PEAKS with their source") from None
+
+
 @dataclasses.dataclass
 class Roofline:
     arch: str
@@ -134,18 +165,20 @@ class Roofline:
     chips: int
     coll_by_kind: Dict[str, float] = dataclasses.field(default_factory=dict)
     memory_per_device: Optional[dict] = None
+    # the chip the step was compiled for (the dry run targets a v5e pod)
+    device_kind: str = "TPU v5 lite"
 
     @property
     def t_compute(self) -> float:
-        return self.flops_per_device / PEAK_FLOPS_BF16
+        return self.flops_per_device / peaks(self.device_kind).flops_bf16
 
     @property
     def t_memory(self) -> float:
-        return self.bytes_per_device / HBM_BW
+        return self.bytes_per_device / peaks(self.device_kind).hbm_bw
 
     @property
     def t_collective(self) -> float:
-        return self.coll_bytes_per_device / ICI_BW
+        return self.coll_bytes_per_device / peaks(self.device_kind).ici_bw
 
     @property
     def bottleneck(self) -> str:

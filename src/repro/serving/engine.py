@@ -74,6 +74,7 @@ from repro.core.linker import bucket, precompute_media_kv, scale_row_ids
 from repro.core.paged_prefill import PagedPrefiller
 from repro.core.policies import POLICIES, PolicyResult, PrefixStore
 from repro.kernels.paged_attn.ops import resolve_backend
+from repro.kernels.platform import pallas_interpret
 from repro.models.layers import INVALID_POS, rope_relink
 from repro.models.model import Model
 from repro.serving.request import Request, State
@@ -248,6 +249,8 @@ class MPICEngine:
                 (self.cfg.decode_slots, self._pages_per_slot),
                 self._scratch_page, np.int32)
             self._paged_backend = resolve_backend(self.cfg.paged_backend)
+            # compiled Mosaic on a TPU, the Pallas interpreter elsewhere
+            self._interpret = pallas_interpret()
             self._batch_cache = None
             q8 = self.pool.quantized
             if self.cfg.donate_decode:
@@ -282,7 +285,7 @@ class MPICEngine:
                 self._prefiller = PagedPrefiller(
                     model, self.pool, self._scratch_page,
                     backend=self._paged_backend,
-                    interpret=jax.default_backend() != "tpu",
+                    interpret=self._interpret,
                     bucket_min=self.cfg.prefill_bucket_min,
                     sharding=self.sharding, param_shardings=self._param_sh)
             self._splice_jit = self._link_jit = None
@@ -812,7 +815,7 @@ class MPICEngine:
         return self.model.decode_step_paged(
             params, tokens, positions, pool_k, pool_v, page_table, lengths,
             write_pages, write_offs, backend=self._paged_backend,
-            interpret=jax.default_backend() != "tpu")
+            interpret=self._interpret)
 
     def _paged_decode_q8_fn(self, params, pool_k, pool_v, k_scales, v_scales,
                             tokens, positions, page_table, lengths,
@@ -823,7 +826,7 @@ class MPICEngine:
             params, tokens, positions, pool_k, pool_v, page_table, lengths,
             write_pages, write_offs, k_scales, v_scales,
             backend=self._paged_backend,
-            interpret=jax.default_backend() != "tpu")
+            interpret=self._interpret)
 
     def _select_token(self, req: Request, logits_row: np.ndarray) -> int:
         """Greedy argmax, or seeded temperature/top-k sampling per request."""
@@ -883,13 +886,37 @@ class MPICEngine:
         return logits
 
     def _decode_paged_step(self, live: List[Request]):
-        """One donated decode step over the page pool for all live slots.
+        """One donated decode step over the page pool for all live slots
+        (operands from :meth:`decode_operands`)."""
+        live, ops = self.decode_operands(live)
+        if not live:
+            return live, None
+        with self.scheduler.compute_window():
+            pool = self.pool
+            if pool.quantized:
+                (logits, pool.k, pool.v,
+                 pool.k_scale, pool.v_scale) = self._decode_jit(
+                    self.params, pool.k, pool.v, pool.k_scale, pool.v_scale,
+                    *ops)
+            else:
+                logits, pool.k, pool.v = self._decode_jit(
+                    self.params, pool.k, pool.v, *ops)
+            logits = np.asarray(logits, np.float32)
+        return live, logits
+
+    def decode_operands(self, live: List[Request]):
+        """Host-built operands of the next paged decode step for ``live``:
+        ``(tokens, positions, page_table, lengths, write_pages,
+        write_offs)``, after growing each slot's pages (and resolving
+        copy-on-write) for the token it writes.  Slots the pool cannot
+        grow finish truncated and drop out of the returned ``live``.
 
         The page table is sliced to the live maximum page count, bucketed to
         the next power of two (bounds retraces to O(log max_seq_len)) — the
         attention work each step scales with the longest *live* cache, not
         with ``max_seq_len``.
         """
+        live = list(live)
         B, ps = self.cfg.decode_slots, self.cfg.page_size
         tokens = np.zeros((B, 1), np.int32)
         positions = np.zeros((B, 1), np.int32)
@@ -928,23 +955,10 @@ class MPICEngine:
             return live, None
         mp_need = max(self.pool.pages_for(r.cur_len + 1) for r in live)
         mp = min(bucket(mp_need, 1), self._pages_per_slot)
-        with self.scheduler.compute_window():
-            pool = self.pool
-            if pool.quantized:
-                (logits, pool.k, pool.v,
-                 pool.k_scale, pool.v_scale) = self._decode_jit(
-                    self.params, pool.k, pool.v, pool.k_scale, pool.v_scale,
-                    jnp.asarray(tokens), jnp.asarray(positions),
-                    jnp.asarray(self._page_tables[:, :mp]),
-                    jnp.asarray(lengths), jnp.asarray(wp), jnp.asarray(wo))
-            else:
-                logits, pool.k, pool.v = self._decode_jit(
-                    self.params, pool.k, pool.v, jnp.asarray(tokens),
-                    jnp.asarray(positions),
-                    jnp.asarray(self._page_tables[:, :mp]),
-                    jnp.asarray(lengths), jnp.asarray(wp), jnp.asarray(wo))
-            logits = np.asarray(logits, np.float32)
-        return live, logits
+        ops = (jnp.asarray(tokens), jnp.asarray(positions),
+               jnp.asarray(self._page_tables[:, :mp]), jnp.asarray(lengths),
+               jnp.asarray(wp), jnp.asarray(wo))
+        return live, ops
 
     def _finish(self, r: Request) -> None:
         r.state = State.DONE

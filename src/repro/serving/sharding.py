@@ -21,6 +21,7 @@ jits under, which turns the model's logical ``shard()`` annotations on.
 """
 from __future__ import annotations
 
+import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.launch import specs as S
@@ -98,3 +99,16 @@ class ServingSharding:
         return {k: NamedSharding(
             self.mesh, S._guard(pspecs[k], v.shape, self.mesh))
             for k, v in cache.items()}
+
+
+def init_params(model, key, mesh=None):
+    """Initialise ``model``'s parameters straight onto where they serve
+    from: the tensor-parallel shardings of ``mesh``, or the default device.
+    One jitted init with the target ``out_shardings``, so no parameter is
+    ever built whole on one device first — at published widths the whole
+    model may not fit one chip."""
+    if mesh is None:
+        return jax.jit(model.init)(key)
+    shapes = jax.eval_shape(model.init, key)
+    shardings = ServingSharding(mesh, model.cfg).params(shapes)
+    return jax.jit(model.init, out_shardings=shardings)(key)

@@ -226,6 +226,22 @@ def case_mrag():
 
 
 def case_cacheblend():
+    """Deviation-probe policy under 4-way TP.  The probe's layer-0 K
+    deviations of linked media are float noise (sharded and unsharded sums
+    round differently), so ranking them picks different tokens on the two
+    engines; pin the selection — as test_paged_prefill's cacheblend parity
+    does — and compare the sharded machinery exactly."""
+    from repro.core import policies as pol_mod
+
+    def fixed_selection(prompt_, dev, r):
+        sel = np.zeros((prompt_.total_len,), bool)
+        sel[~prompt_.media_mask()] = True          # all text
+        media_idx = np.nonzero(prompt_.media_mask())[0]
+        sel[media_idx[::4]] = True                 # every 4th media token
+        assert dev.shape == (prompt_.total_len,)
+        return sel
+
+    pol_mod.sel_mod.cacheblend_selection = fixed_selection
     cfg = _cfg()
     base, shrd = _engine_pair(cfg, EngineConfig(max_seq_len=128,
                                                 decode_slots=2,

@@ -326,6 +326,12 @@ def test_network_pull_and_tier_accounting(tmp_path):
         # admitted block is now local: the second get never hits the wire
         assert lib.get("u", "m") is not None
         assert lib.network.counters["hits"] == 1   # still just one fetch
+        # the handler thread counts a block after its body is sent, which
+        # the client may already have read: give it a moment to finish
+        deadline = time.monotonic() + 5.0
+        while (server.stats()["served_blocks"] < 1
+               and time.monotonic() < deadline):
+            time.sleep(0.01)
         assert server.stats()["served_blocks"] == 1
         # scope isolation across the wire: bob cannot pull alice's block
         assert lib.get("bob", "m") is None

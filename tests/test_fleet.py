@@ -9,6 +9,8 @@ supervisor to finish everything and drain cleanly.  It is the CI fleet
 job; pytest-timeout (marker below + the global cap) guards against a
 wedged fleet hanging the suite.
 """
+import time
+
 import numpy as np
 import pytest
 
@@ -104,8 +106,8 @@ def test_heartbeat_view_routes_to_warm_host():
 @pytest.mark.timeout(560)
 def test_fleet_smoke_kill_one_host_drain_completes(tmp_path):
     cfg = get_smoke_config("llava-1.6-7b")
-    fleet = FleetSupervisor(2, base_dir=str(tmp_path), hbm_bytes=1,
-                            host_bytes=1, max_seq_len=1024,
+    fleet = FleetSupervisor(2, base_dir=str(tmp_path), smoke=True,
+                            hbm_bytes=1, host_bytes=1, max_seq_len=1024,
                             heartbeat_s=0.2, miss_threshold=3,
                             linger_s=30.0)
     try:
@@ -161,7 +163,8 @@ def test_fleet_freeze_on_a_kill_a_thaw_on_b(tmp_path):
                        max_new_tokens=8, policy="full_recompute", seed=5,
                        **kw)
 
-    fleet = FleetSupervisor(2, base_dir=str(tmp_path), slots=2,
+    fleet = FleetSupervisor(2, base_dir=str(tmp_path), smoke=True,
+                            slots=2,
                             heartbeat_s=0.2, miss_threshold=3,
                             linger_s=30.0)
     try:
@@ -224,7 +227,8 @@ def test_fleet_single_host_serves_and_drains(tmp_path):
     """1-host fleet: the degenerate topology must still serve + drain
     (covers the supervisor without the failover machinery)."""
     cfg = get_smoke_config("llava-1.6-7b")
-    fleet = FleetSupervisor(1, base_dir=str(tmp_path), max_seq_len=1024,
+    fleet = FleetSupervisor(1, base_dir=str(tmp_path), smoke=True,
+                            max_seq_len=1024,
                             heartbeat_s=0.25, linger_s=30.0)
     try:
         fleet.start()
@@ -241,3 +245,38 @@ def test_fleet_single_host_serves_and_drains(tmp_path):
         fleet.drain(timeout_s=120)
     finally:
         fleet.stop()
+
+
+# ---------------------------------------------------------------------------
+# one process per chip
+# ---------------------------------------------------------------------------
+
+def test_fleet_refuses_more_hosts_than_chips(monkeypatch, tmp_path):
+    """On a TPU machine every engine host needs a chip of its own: a fleet
+    larger than the attached chips is refused before anything spawns."""
+    import repro.launch.device as device
+    monkeypatch.setattr(device, "attached_tpu_chips", lambda: 1)
+    with pytest.raises(ValueError, match="one process per chip"):
+        FleetSupervisor(2, base_dir=str(tmp_path), smoke=True,
+                        env={"JAX_PLATFORMS": "tpu"})
+    # as many hosts as chips is fine (nothing is spawned until start())
+    FleetSupervisor(1, base_dir=str(tmp_path), smoke=True,
+                    env={"JAX_PLATFORMS": "tpu"})
+
+
+@pytest.mark.timeout(180)
+def test_fleet_host_without_its_chip_fails_fast(tmp_path):
+    """A host that cannot get the platform the supervisor expects (here:
+    told to find a TPU on a CPU machine, as a second host finds the chip
+    held by the first) exits at start-up, and ``start`` reports it at once
+    instead of waiting out the start grace."""
+    fleet = FleetSupervisor(1, base_dir=str(tmp_path), smoke=True,
+                            start_grace_s=600.0)
+    fleet.platform = "tpu"
+    t0 = time.monotonic()
+    try:
+        with pytest.raises(RuntimeError, match="exited with"):
+            fleet.start(timeout_s=600.0)
+    finally:
+        fleet.stop()
+    assert time.monotonic() - t0 < 120

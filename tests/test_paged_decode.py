@@ -29,8 +29,12 @@ def _tiny_cfg(hq, hkv, window=0):
 
 # fp32 pool is exact vs the dense path; the int8 pool dequantizes
 # in-kernel from per-page scales, so logits carry the KV quantization
-# error — bounded well under 0.05 on these tiny models (greedy argmax
-# stays identical; see test_engine_paged_int8_matches_fp32_pool)
+# error: each K/V element is off by at most half a step of amax/127 of its
+# page, and on these tiny models (logits of magnitude ~1) that moves a
+# logit by at most ~0.04 — under 0.05, which a coarser pool (e.g. 4-bit
+# steps of amax/7) would exceed.  Greedy argmax can still flip where the
+# top two logits are nearer than that (see
+# test_engine_paged_int8_matches_fp32_pool)
 POOL_TOL = {"float32": dict(atol=1e-4, rtol=1e-4),
             "int8": dict(atol=5e-2, rtol=0)}
 
@@ -161,12 +165,24 @@ def test_engine_paged_matches_dense(fp32_llava):
     assert "RAG1" in reqs_p[0].linked_media
 
 
-def test_engine_paged_int8_matches_fp32_pool(fp32_llava):
+def test_engine_paged_int8_matches_fp32_pool(fp32_llava, monkeypatch):
     """End to end with ``pool_dtype='int8'``: the same requests through the
-    int8-resident pool produce the SAME greedy continuations as the fp32
-    pool (deterministic seeds; the per-page quantization error never flips
-    an argmax on this model), and the pool reports quantized buffers."""
+    int8-resident pool give the fp32 pool's logits within
+    ``POOL_TOL['int8']`` — at the first token (prefill through the int8
+    pages) and at every decode step fed the same tokens — and the pool
+    reports quantized buffers.  Sampled tokens are not compared past a
+    divergence: with random weights the top two logits can sit closer than
+    the KV quantization error, so one rounding flips an argmax."""
     cfg, model, params = fp32_llava
+    rows = {}
+    select = MPICEngine._select_token
+
+    def spy(self, req, logits_row):
+        key = (self.pool.quantized, req.req_id)
+        rows.setdefault(key, []).append(np.array(logits_row))
+        return select(self, req, logits_row)
+
+    monkeypatch.setattr(MPICEngine, "_select_token", spy)
     eng_q, reqs_q = _engine_outputs(cfg, model, params, paged=True,
                                     pool_dtype="int8")
     eng_f, reqs_f = _engine_outputs(cfg, model, params, paged=True)
@@ -174,8 +190,13 @@ def test_engine_paged_int8_matches_fp32_pool(fp32_llava):
     assert not eng_f.pool.quantized
     for rq, rf in zip(reqs_q, reqs_f):
         assert rq.done and rf.done
-        assert rq.output_tokens == rf.output_tokens
         assert rq.linked_media == rf.linked_media
+        lq, lf = rows[(True, rq.req_id)], rows[(False, rf.req_id)]
+        assert len(lq) == len(lf) == len(rq.output_tokens)
+        for step, (a, b) in enumerate(zip(lq, lf)):
+            np.testing.assert_allclose(a, b, **POOL_TOL["int8"])
+            if rq.output_tokens[step] != rf.output_tokens[step]:
+                break       # later steps were fed different tokens
     # pages recycle identically (scale buffers free with their pages)
     assert eng_q.pool.free_pages == eng_q.pool.cfg.num_pages - 1
 

@@ -65,7 +65,7 @@ def test_quant_block_scales_tighter_than_whole_seq():
 @settings(max_examples=10, deadline=None)
 @given(seed=st.integers(0, 2 ** 16),
        bt=st.sampled_from([4, 8, 16]), whole_v=st.booleans())
-def test_quant_block_spool_wire_roundtrip(seed, bt, whole_v, tmp_path):
+def test_quant_block_spool_wire_roundtrip(seed, bt, whole_v):
     """The npz wire format must carry block granularity explicitly
     (ceil-division makes it non-inferable from shapes) — a spooled
     block-granular entry must unspool bit-identical with block_tokens

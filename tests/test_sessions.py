@@ -23,11 +23,8 @@ import jax
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-except ImportError:                                   # pragma: no cover
-    from tests._hypothesis_fallback import given, settings, strategies as st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.cache import TIER_DISK
 from repro.cache.paged import PagedConfig, PagedKVPool
