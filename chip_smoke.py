@@ -16,7 +16,8 @@ token pool of 2.1 GB, and the library's images).
 One chip: every request must finish with tokens and none may fail; the
 engine must have resolved the compiled Pallas kernels (``interpret`` off,
 ``tpu_custom_call`` in the decode step's HLO); one decode step's logits
-from the Pallas kernels must match the ``ref`` backend's on the chip.
+from the Pallas kernels must match the ``ref`` backend's on the chip, and
+one paged-attention call on the served pages must match float64 numpy.
 
 ``--mesh 1x4`` runs only the sharded path and what it is compared with:
 the full 32-layer model (which one chip cannot hold) serving a few
@@ -56,6 +57,13 @@ MAX_SEQ_LEN = 2048
 # an RMS difference of 5% of the reference's RMS.  An fp8 computation
 # (2**-4 ~ 6% per op) would exceed it at the first layer.
 LOGIT_RMS_RTOL = 0.05
+# One paged-attention call on the pool's bf16 pages, against float64 numpy
+# on the same inputs: the kernel accumulates in f32 and rounds its output
+# to bf16 (at most 2**-8 ~ 0.4% relative, ~0.16% in RMS).  Allowed: 0.5%
+# in RMS.
+ATTN_RMS_RTOL = 0.005
+
+
 def _serve_args(*extra):
     from repro.launch import serve
     return serve.build_parser().parse_args(
@@ -69,21 +77,22 @@ def _log(msg: str) -> None:
     print(f"[chip_smoke] {msg}", flush=True)
 
 
-def _close(label: str, got, want) -> float:
-    """RMS of ``got - want`` over the RMS of ``want``; raises past the
-    tolerance (the largest difference is printed beside it)."""
+def _close(label: str, got, want, limit: float = LOGIT_RMS_RTOL,
+           what: str = "logits") -> float:
+    """RMS of ``got - want`` over the RMS of ``want``; raises past
+    ``limit`` (the largest difference is printed beside it)."""
     import numpy as np
     got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
     if not (np.isfinite(got).all() and np.isfinite(want).all()):
-        raise AssertionError(f"{label}: non-finite logits")
+        raise AssertionError(f"{label}: non-finite {what}")
     rms = float(np.sqrt(np.mean((got - want) ** 2)
                         / max(np.mean(want ** 2), 1e-12)))
     worst = float(np.abs(got - want).max() / max(np.abs(want).max(), 1e-6))
-    _log(f"{label}: rms diff / rms = {rms:.5f} (limit {LOGIT_RMS_RTOL}); "
+    _log(f"{label}: rms diff / rms = {rms:.5f} (limit {limit}); "
          f"max diff / max = {worst:.5f}")
-    if rms > LOGIT_RMS_RTOL:
-        raise AssertionError(f"{label}: logits differ by {rms:.5f} in RMS "
-                             f"(> {LOGIT_RMS_RTOL})")
+    if rms > limit:
+        raise AssertionError(f"{label}: {what} differ by {rms:.5f} in RMS "
+                             f"(> {limit})")
     return rms
 
 
@@ -205,6 +214,41 @@ def _check_decode_step(server) -> None:
     got, want = logits("pallas"), logits("ref")
     _close(f"decode step, pallas vs ref, {len(slots)} slots",
            got[slots], want[slots])
+    _check_attention(eng, slots, ops[2], ops[3])
+
+
+def _check_attention(eng, slots, page_table, lengths) -> None:
+    """One paged-attention call over the engine's layer-0 pages and the
+    live slots' page tables, each backend against float64 numpy."""
+    import jax
+    import numpy as np
+
+    from repro.kernels.paged_attn.ops import paged_attention_call
+    cfg = eng.model.cfg
+    k, v = eng.pool.k[0], eng.pool.v[0]              # (P, ps, Hkv, Dh)
+    table = np.asarray(page_table)[slots]
+    lens = np.asarray(lengths)[slots]
+    q = jax.random.normal(jax.random.PRNGKey(1),
+                          (len(slots), cfg.num_heads, cfg.head_dim), k.dtype)
+
+    # float64 oracle on the same (rounded) inputs
+    qn = np.asarray(q, np.float64)
+    kn = np.asarray(k, np.float64)[table].reshape(len(slots), -1,
+                                                  *k.shape[2:])
+    vn = np.asarray(v, np.float64)[table].reshape(kn.shape)
+    rep = cfg.num_heads // k.shape[2]
+    kn, vn = np.repeat(kn, rep, axis=2), np.repeat(vn, rep, axis=2)
+    s = np.einsum("bhd,bkhd->bhk", qn, kn) / np.sqrt(cfg.head_dim)
+    s = np.where(np.arange(s.shape[-1]) < lens[:, None, None], s, -np.inf)
+    p = np.exp(s - s.max(-1, keepdims=True))
+    want = np.einsum("bhk,bkhd->bhd", p / p.sum(-1, keepdims=True), vn)
+
+    for backend in ("pallas", "ref"):
+        got = jax.jit(lambda *a: paged_attention_call(
+            *a, backend=backend, interpret=eng._interpret))(
+            q, k, v, table, lens)
+        _close(f"layer-0 paged attention, {backend} vs float64, "
+               f"{len(slots)} slots", got, want, ATTN_RMS_RTOL, "outputs")
 
 
 def _check_engine_kernels(eng) -> None:

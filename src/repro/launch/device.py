@@ -12,7 +12,6 @@
 """
 from __future__ import annotations
 
-import glob
 import os
 from pathlib import Path
 from typing import Mapping, Optional
@@ -20,13 +19,32 @@ from typing import Mapping, Optional
 # the checkout this module lives in: src/repro/launch/device.py -> root
 CHECKOUT = Path(__file__).resolve().parents[3]
 COMPILE_CACHE_DIR = CHECKOUT / ".jax_cache"
+GOOGLE_PCI_VENDOR = "0x1ae0"
 
 
-def attached_tpu_chips() -> int:
-    """TPU chips this machine exposes (device nodes ``/dev/accel*``, or
-    ``/dev/vfio/<n>`` on newer TPU VMs); 0 on a machine without TPUs."""
-    return len(glob.glob("/dev/accel[0-9]*")
-               or glob.glob("/dev/vfio/[0-9]*"))
+def _google_vfio_group(root: Path, group: str) -> bool:
+    """Whether VFIO group ``group`` holds a PCI device of Google's."""
+    devices = root / "sys" / "kernel" / "iommu_groups" / group / "devices"
+    for vendor in devices.glob("*/vendor"):
+        try:
+            if vendor.read_text().strip() == GOOGLE_PCI_VENDOR:
+                return True
+        except OSError:
+            pass
+    return False
+
+
+def attached_tpu_chips(root: str = "/") -> int:
+    """TPU chips this machine exposes: device nodes ``/dev/accel*``, or on
+    newer TPU VMs the ``/dev/vfio/<group>`` nodes whose IOMMU group holds a
+    PCI device with Google's vendor id (any other VFIO passthrough device
+    is no TPU).  0 on a machine without TPUs."""
+    r = Path(root)
+    accel = list(r.glob("dev/accel[0-9]*"))
+    if accel:
+        return len(accel)
+    return sum(_google_vfio_group(r, node.name)
+               for node in r.glob("dev/vfio/[0-9]*"))
 
 
 def expected_platform(environ: Optional[Mapping[str, str]] = None

@@ -383,15 +383,14 @@ def host_main(args) -> int:
     from repro.cache.net import (KVPeerServer, PeerTransport,
                                  ReusableThreadingHTTPServer)
     from repro.configs import serving_config
-    from repro.launch.device import require_platform
+    from repro.launch.device import expected_platform, require_platform
     from repro.models import build_model
     from repro.serving import EngineConfig, MPICEngine
     from repro.serving.sharding import init_params
 
-    # the supervisor spawns one host per chip and says which platform it
-    # must get: a host that finds its chip taken must die here, loudly,
-    # not serve on the CPU JAX falls back to
-    require_platform(args.platform or None)
+    # one host per chip: a host that finds its chip taken must die here,
+    # loudly, not serve on the CPU JAX falls back to
+    require_platform(expected_platform())
     cfg = serving_config(args.arch, smoke=args.smoke, layers=args.layers)
     model = build_model(cfg)
     params = init_params(model, jax.random.PRNGKey(0))
@@ -561,9 +560,8 @@ class FleetSupervisor:
         from repro.serving.router import make_router
         assert hosts >= 1
         self._env = env
-        self.platform = expected_platform(
-            os.environ if env is None else env)
-        if self.platform == "tpu":
+        # each host, spawned with this env, expects the same platform
+        if expected_platform(os.environ if env is None else env) == "tpu":
             chips = attached_tpu_chips()
             if hosts > chips:
                 raise ValueError(
@@ -622,8 +620,7 @@ class FleetSupervisor:
                "--hbm-bytes", str(self.hbm_bytes),
                "--host-bytes", str(self.host_bytes),
                "--freeze-idle-s", str(self.freeze_idle_s),
-               "--layers", str(self.layers),
-               "--platform", self.platform or ""]
+               "--layers", str(self.layers)]
         if self.smoke:
             cmd.append("--smoke")
         env = dict(os.environ if self._env is None else self._env)
@@ -1110,9 +1107,6 @@ def main() -> int:
                          "published widths")
     ap.add_argument("--layers", type=int, default=0,
                     help=">0: cut the model to this many decoder layers")
-    ap.add_argument("--platform", default="",
-                    help="internal: the platform a host must initialise "
-                         "JAX on (set by the supervisor)")
     ap.add_argument("--ctrl-port", type=int, default=0)
     ap.add_argument("--block-port", type=int, default=0)
     ap.add_argument("--spool-dir", default=os.path.join(

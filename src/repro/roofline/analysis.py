@@ -152,6 +152,11 @@ def peaks(device_kind: str) -> ChipPeaks:
             f"repro.roofline.analysis.PEAKS with their source") from None
 
 
+# the dry run compiles for a v5e pod (repro.launch.mesh), so its terms
+# use that chip's peaks
+DRYRUN_DEVICE_KIND = "TPU v5 lite"
+
+
 @dataclasses.dataclass
 class Roofline:
     arch: str
@@ -165,20 +170,18 @@ class Roofline:
     chips: int
     coll_by_kind: Dict[str, float] = dataclasses.field(default_factory=dict)
     memory_per_device: Optional[dict] = None
-    # the chip the step was compiled for (the dry run targets a v5e pod)
-    device_kind: str = "TPU v5 lite"
 
     @property
     def t_compute(self) -> float:
-        return self.flops_per_device / peaks(self.device_kind).flops_bf16
+        return self.flops_per_device / peaks(DRYRUN_DEVICE_KIND).flops_bf16
 
     @property
     def t_memory(self) -> float:
-        return self.bytes_per_device / peaks(self.device_kind).hbm_bw
+        return self.bytes_per_device / peaks(DRYRUN_DEVICE_KIND).hbm_bw
 
     @property
     def t_collective(self) -> float:
-        return self.coll_bytes_per_device / peaks(self.device_kind).ici_bw
+        return self.coll_bytes_per_device / peaks(DRYRUN_DEVICE_KIND).ici_bw
 
     @property
     def bottleneck(self) -> str:

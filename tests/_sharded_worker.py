@@ -230,8 +230,11 @@ def case_cacheblend():
     deviations of linked media are float noise (sharded and unsharded sums
     round differently), so ranking them picks different tokens on the two
     engines; pin the selection — as test_paged_prefill's cacheblend parity
-    does — and compare the sharded machinery exactly."""
+    does — and compare the sharded machinery exactly.  The deviations the
+    sharded probe measures must still match the unsharded probe's."""
     from repro.core import policies as pol_mod
+
+    devs = []                                      # one per engine, in order
 
     def fixed_selection(prompt_, dev, r):
         sel = np.zeros((prompt_.total_len,), bool)
@@ -239,6 +242,7 @@ def case_cacheblend():
         media_idx = np.nonzero(prompt_.media_mask())[0]
         sel[media_idx[::4]] = True                 # every 4th media token
         assert dev.shape == (prompt_.total_len,)
+        devs.append(np.asarray(dev, np.float64))
         return sel
 
     pol_mod.sel_mod.cacheblend_selection = fixed_selection
@@ -249,6 +253,10 @@ def case_cacheblend():
     _run_pair(base, shrd, lambda: [
         Request(prompt=_prompt(cfg, 7), max_new_tokens=4,
                 policy="cacheblend", policy_kwargs={"r": 0.25})])
+    # f32: text deviations are O(10), media ones O(1e-5); the two probes
+    # agree to ~1e-5 absolute
+    dev_base, dev_shrd = devs
+    np.testing.assert_allclose(dev_shrd, dev_base, rtol=1e-5, atol=1e-4)
 
 
 def case_dense():

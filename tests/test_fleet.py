@@ -9,6 +9,7 @@ supervisor to finish everything and drain cleanly.  It is the CI fleet
 job; pytest-timeout (marker below + the global cap) guards against a
 wedged fleet hanging the suite.
 """
+import os
 import time
 
 import numpy as np
@@ -265,14 +266,18 @@ def test_fleet_refuses_more_hosts_than_chips(monkeypatch, tmp_path):
 
 
 @pytest.mark.timeout(180)
-def test_fleet_host_without_its_chip_fails_fast(tmp_path):
-    """A host that cannot get the platform the supervisor expects (here:
-    told to find a TPU on a CPU machine, as a second host finds the chip
-    held by the first) exits at start-up, and ``start`` reports it at once
-    instead of waiting out the start grace."""
+def test_fleet_host_without_its_chip_fails_fast(monkeypatch, tmp_path):
+    """A host that cannot get the platform it expects (here: told to find
+    a TPU on a CPU machine, as a second host finds the chip held by the
+    first) exits at start-up, and ``start`` reports it at once instead of
+    waiting out the start grace."""
+    import repro.launch.device as device
+    monkeypatch.setattr(device, "attached_tpu_chips", lambda: 1)
+    env = {**os.environ, "JAX_PLATFORMS": "tpu",
+           # off a TPU VM: no metadata-server lookup
+           "TPU_SKIP_MDS_QUERY": "1"}
     fleet = FleetSupervisor(1, base_dir=str(tmp_path), smoke=True,
-                            start_grace_s=600.0)
-    fleet.platform = "tpu"
+                            start_grace_s=600.0, env=env)
     t0 = time.monotonic()
     try:
         with pytest.raises(RuntimeError, match="exited with"):

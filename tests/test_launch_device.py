@@ -58,6 +58,38 @@ def test_expected_platform_follows_jax_platforms(env, want):
     assert expected_platform(env) == want
 
 
+def _node(path: Path, text: str = "") -> None:
+    path.parent.mkdir(parents=True, exist_ok=True)
+    path.write_text(text)
+
+
+@pytest.mark.parametrize("vendors,want", [
+    ({"2": "0x1ae0"}, 1),                       # a TPU VM's chip
+    ({"2": "0x1ae0", "5": "0x1ae0"}, 2),
+    ({"7": "0x10de"}, 0),                       # a GPU passed through
+    ({"2": "0x1ae0", "7": "0x10de"}, 1),
+    ({"3": None}, 0),                           # group without sysfs entry
+])
+def test_attached_tpu_chips_counts_only_google_vfio_groups(tmp_path, vendors,
+                                                           want):
+    from repro.launch.device import attached_tpu_chips
+    _node(tmp_path / "dev" / "vfio" / "vfio")   # the container node
+    for group, vendor in vendors.items():
+        _node(tmp_path / "dev" / "vfio" / group)
+        if vendor is not None:
+            _node(tmp_path / "sys" / "kernel" / "iommu_groups" / group
+                  / "devices" / "0000:00:04.0" / "vendor", vendor + "\n")
+    assert attached_tpu_chips(str(tmp_path)) == want
+
+
+def test_attached_tpu_chips_counts_accel_nodes(tmp_path):
+    from repro.launch.device import attached_tpu_chips
+    for i in range(4):
+        _node(tmp_path / "dev" / f"accel{i}")
+    assert attached_tpu_chips(str(tmp_path)) == 4
+    assert attached_tpu_chips(str(tmp_path / "empty")) == 0
+
+
 def test_require_platform_refuses_a_fallback():
     here = jax.devices()[0].platform
     info = require_platform(here)
