@@ -25,6 +25,7 @@ from repro.core.segments import Prompt
 from repro.core.select import selection_indices
 from repro.models.layers import INVALID_POS, rope_relink
 from repro.models.model import Model
+from repro.tracing import span
 
 
 @dataclasses.dataclass
@@ -62,11 +63,12 @@ class PagedLinkResult:
 
 def selection_arrays(prompt: Prompt, d_model: int, sel_idx: np.ndarray):
     """Gather the per-selected-token inputs (ids, media embeds, media mask)."""
-    flat_tokens = prompt.flat_tokens()
-    media_mask = prompt.media_mask()
-    media_embeds = prompt.flat_media_embeds(d_model)
-    return (flat_tokens[sel_idx], media_embeds[sel_idx],
-            media_mask[sel_idx])
+    with span("mpic.prefill.pack"):
+        flat_tokens = prompt.flat_tokens()
+        media_mask = prompt.media_mask()
+        media_embeds = prompt.flat_media_embeds(d_model)
+        return (flat_tokens[sel_idx], media_embeds[sel_idx],
+                media_mask[sel_idx])
 
 
 def precompute_media_kv(model: Model, params, embeds: jnp.ndarray):
@@ -227,91 +229,27 @@ def link_paged(model: Model, prompt: Prompt, library,
     """
     cfg = model.cfg
     total = prompt.total_len
-    ps = pool.cfg.page_size
-    sel, placed, misses = _gather_placements(prompt, library, selection,
-                                             entries)
+    with span("mpic.link.gather"):
+        sel, placed, misses = _gather_placements(prompt, library, selection,
+                                                 entries)
     forced = sel & ~selection                   # miss-driven recomputes
     sel_idx = selection_indices(sel)
 
     if placed:
-        idx = np.concatenate([np.arange(off, off + n)
-                              for off, _, n in placed])
-        delta = np.concatenate([np.full(n, off, np.int32)
-                                for off, _, n in placed])
-        n_placed = len(idx)
-        b = min(bucket(n_placed), max(pool.cfg.page_size, 8) *
-                max(len(page_row), 1))
-        pad = b - n_placed
-        if pad > 0:
-            delta = np.concatenate([delta, np.zeros(pad, np.int32)])
-        pages = np.full((b,), scratch_page, np.int32)
-        offs = np.zeros((b,), np.int32)
-        pages[:n_placed] = np.asarray(page_row)[idx // ps]
-        offs[:n_placed] = idx % ps
+        with span("mpic.link.pack"):
+            direct, host = _pack_placed(pool, placed, page_row,
+                                        scratch_page)
+        with span("mpic.link.upload"):
+            ops = [jnp.asarray(a) for a in host]
         relink = bool(cfg.rope_theta) and not cfg.learned_pos_emb
-        direct = (getattr(pool, "quantized", False)
-                  and all(getattr(e, "payload", None) is not None
-                          and e.payload.qk is not None
-                          and e.payload.qk.block_tokens
-                          == e.payload.qv.block_tokens
-                          for _, e, _ in placed))
-        if direct:
-            # spool→pool zero copy: every placed entry is int8-resident, so
-            # its bytes rescale straight onto the pool's page grid inside
-            # one donated jit — no dequantize→requantize fp round trip and
-            # no fp copy of any block.  Scale rows from all segments stack
-            # into one (L, rows, H, Dh) operand; ``seg_ids`` maps each
-            # placed token to its row (whole-seq or block_tokens granular).
-            qks, qvs, ksr, vsr, sids = [], [], [], [], []
-            base = 0
-            for off, e, n in placed:
-                qk, qv = e.payload.qk, e.payload.qv
-                qks.append(qk.q[:, :n])
-                qvs.append(qv.q[:, :n])
-                ksr.append(qk.scale)
-                vsr.append(qv.scale)
-                sids.append(base + scale_row_ids(n, qk))
-                base += qk.scale.shape[1]
-            qk_cat = np.concatenate(qks, axis=1)
-            qv_cat = np.concatenate(qvs, axis=1)
-            sid = np.concatenate(sids)
-            if pad > 0:
-                z = np.zeros(qk_cat.shape[:1] + (pad,) + qk_cat.shape[2:],
-                             np.int8)
-                qk_cat = np.concatenate([qk_cat, z], axis=1)
-                qv_cat = np.concatenate([qv_cat, z], axis=1)
-                sid = np.concatenate([sid, np.zeros(pad, np.int32)])
-            ks_cat = np.concatenate(ksr, axis=1)
-            vs_cat = np.concatenate(vsr, axis=1)
-            # bucket the scale-row axis too (pad rows are never referenced)
-            rpad = bucket(base, 1) - base
-            if rpad > 0:
-                zr = np.ones(ks_cat.shape[:1] + (rpad,) + ks_cat.shape[2:],
-                             np.float32)
-                ks_cat = np.concatenate([ks_cat, zr], axis=1)
-                vs_cat = np.concatenate([vs_cat, zr], axis=1)
-            pool.link_write_q8(
-                jnp.asarray(pages), jnp.asarray(offs),
-                jnp.asarray(qk_cat), jnp.asarray(ks_cat),
-                jnp.asarray(qv_cat), jnp.asarray(vs_cat),
-                jnp.asarray(sid), jnp.asarray(delta),
-                theta=cfg.rope_theta, relink=relink)
-            if library is not None:
-                library.note_direct_link(len(placed))
-        else:
-            k_cat = np.concatenate([np.asarray(e.k) for _, e, _ in placed],
-                                   axis=1)
-            v_cat = np.concatenate([np.asarray(e.v) for _, e, _ in placed],
-                                   axis=1)
-            if pad > 0:
-                zeros = np.zeros(k_cat.shape[:1] + (pad,) + k_cat.shape[2:],
-                                 k_cat.dtype)
-                k_cat = np.concatenate([k_cat, zeros], axis=1)
-                v_cat = np.concatenate([v_cat, zeros], axis=1)
-            pool.link_write(
-                jnp.asarray(pages), jnp.asarray(offs), jnp.asarray(k_cat),
-                jnp.asarray(v_cat), jnp.asarray(delta),
-                theta=cfg.rope_theta, relink=relink)
+        with span("mpic.link.write"):
+            if direct:
+                pool.link_write_q8(*ops, theta=cfg.rope_theta,
+                                   relink=relink)
+            else:
+                pool.link_write(*ops, theta=cfg.rope_theta, relink=relink)
+        if direct and library is not None:
+            library.note_direct_link(len(placed))
 
     sel_tokens, sel_media_embeds, sel_media_mask = selection_arrays(
         prompt, cfg.d_model, sel_idx)
@@ -326,6 +264,77 @@ def link_paged(model: Model, prompt: Prompt, library,
         total=total,
         forced=forced,
     )
+
+
+def _pack_placed(pool, placed, page_row, scratch_page):
+    """Host operands of the pool scatter that links ``placed`` segments:
+    ``(direct, arrays)``, where ``direct`` picks ``pool.link_write_q8``
+    over ``pool.link_write`` and ``arrays`` are its positional operands
+    (pages, offsets, K/V or their int8 rows and scales, delta)."""
+    ps = pool.cfg.page_size
+    idx = np.concatenate([np.arange(off, off + n) for off, _, n in placed])
+    delta = np.concatenate([np.full(n, off, np.int32)
+                            for off, _, n in placed])
+    n_placed = len(idx)
+    b = min(bucket(n_placed), max(ps, 8) * max(len(page_row), 1))
+    pad = b - n_placed
+    if pad > 0:
+        delta = np.concatenate([delta, np.zeros(pad, np.int32)])
+    pages = np.full((b,), scratch_page, np.int32)
+    offs = np.zeros((b,), np.int32)
+    pages[:n_placed] = np.asarray(page_row)[idx // ps]
+    offs[:n_placed] = idx % ps
+    direct = (getattr(pool, "quantized", False)
+              and all(getattr(e, "payload", None) is not None
+                      and e.payload.qk is not None
+                      and e.payload.qk.block_tokens
+                      == e.payload.qv.block_tokens
+                      for _, e, _ in placed))
+    if direct:
+        # spool→pool zero copy: every placed entry is int8-resident, so
+        # its bytes rescale straight onto the pool's page grid inside
+        # one donated jit — no dequantize→requantize fp round trip and
+        # no fp copy of any block.  Scale rows from all segments stack
+        # into one (L, rows, H, Dh) operand; ``seg_ids`` maps each
+        # placed token to its row (whole-seq or block_tokens granular).
+        qks, qvs, ksr, vsr, sids = [], [], [], [], []
+        base = 0
+        for off, e, n in placed:
+            qk, qv = e.payload.qk, e.payload.qv
+            qks.append(qk.q[:, :n])
+            qvs.append(qv.q[:, :n])
+            ksr.append(qk.scale)
+            vsr.append(qv.scale)
+            sids.append(base + scale_row_ids(n, qk))
+            base += qk.scale.shape[1]
+        qk_cat = np.concatenate(qks, axis=1)
+        qv_cat = np.concatenate(qvs, axis=1)
+        sid = np.concatenate(sids)
+        if pad > 0:
+            z = np.zeros(qk_cat.shape[:1] + (pad,) + qk_cat.shape[2:],
+                         np.int8)
+            qk_cat = np.concatenate([qk_cat, z], axis=1)
+            qv_cat = np.concatenate([qv_cat, z], axis=1)
+            sid = np.concatenate([sid, np.zeros(pad, np.int32)])
+        ks_cat = np.concatenate(ksr, axis=1)
+        vs_cat = np.concatenate(vsr, axis=1)
+        # bucket the scale-row axis too (pad rows are never referenced)
+        rpad = bucket(base, 1) - base
+        if rpad > 0:
+            zr = np.ones(ks_cat.shape[:1] + (rpad,) + ks_cat.shape[2:],
+                         np.float32)
+            ks_cat = np.concatenate([ks_cat, zr], axis=1)
+            vs_cat = np.concatenate([vs_cat, zr], axis=1)
+        return True, (pages, offs, qk_cat, ks_cat, qv_cat, vs_cat, sid,
+                      delta)
+    k_cat = np.concatenate([np.asarray(e.k) for _, e, _ in placed], axis=1)
+    v_cat = np.concatenate([np.asarray(e.v) for _, e, _ in placed], axis=1)
+    if pad > 0:
+        zeros = np.zeros(k_cat.shape[:1] + (pad,) + k_cat.shape[2:],
+                         k_cat.dtype)
+        k_cat = np.concatenate([k_cat, zeros], axis=1)
+        v_cat = np.concatenate([v_cat, zeros], axis=1)
+    return False, (pages, offs, k_cat, v_cat, delta)
 
 
 def reselect_paged(model: Model, prompt: Prompt, link: PagedLinkResult,

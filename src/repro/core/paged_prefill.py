@@ -42,6 +42,7 @@ from repro.core.linker import (
 )
 from repro.core.segments import Prompt
 from repro.models.model import Model
+from repro.tracing import span
 
 
 class PagedPrefiller:
@@ -123,8 +124,27 @@ class PagedPrefiller:
         first-output-token logits, matching the dense ``_selective_step``).
         """
         pool = self.pool
-        ps = pool.cfg.page_size
-        page_row = np.asarray(page_row)
+        with span("mpic.prefill.pack"):
+            host = self._pack(link, np.asarray(page_row))
+        ctx = (self.sharding.activate() if self.sharding is not None
+               else contextlib.nullcontext())
+        with ctx, span("mpic.prefill.dispatch"):
+            # logical shard() annotations apply at trace time
+            if self.quantized:
+                out, pool.k, pool.v, pool.k_scale, pool.v_scale = self._jit(
+                    params, pool.k, pool.v, pool.k_scale, pool.v_scale,
+                    *host)
+            else:
+                out, pool.k, pool.v = self._jit(params, pool.k, pool.v,
+                                                *host)
+        with span("mpic.prefill.fetch"):
+            return np.asarray(out, np.float32)
+
+    def _pack(self, link: PagedLinkResult, page_row: np.ndarray) -> tuple:
+        """The prefill's host operands after the pool buffers: the
+        selection padded to its bucket, the page table sliced to the
+        bucketed live page count, and the last real row's index."""
+        ps = self.pool.cfg.page_size
         n = len(link.sel_idx)
         sb = bucket(n, self.bucket_min)
 
@@ -143,24 +163,13 @@ class PagedPrefiller:
         wp[:n] = page_row[link.sel_idx // ps]
         wo[:n] = link.sel_idx % ps
 
-        mp = min(bucket(pool.pages_for(link.total)), len(page_row))
-        ctx = (self.sharding.activate() if self.sharding is not None
-               else contextlib.nullcontext())
-        host = (np.asarray(tokens[None]), np.asarray(positions[None]),
+        mp = min(bucket(self.pool.pages_for(link.total)), len(page_row))
+        return (np.asarray(tokens[None]), np.asarray(positions[None]),
                 np.asarray(emb[None]), np.asarray(mask[None]),
                 np.asarray(page_row[None, :mp]),
                 np.asarray([link.total], np.int32),
                 np.asarray(wp[None]), np.asarray(wo[None]),
                 np.int32(max(n - 1, 0)))
-        with ctx:   # logical shard() annotations apply at trace time
-            if self.quantized:
-                out, pool.k, pool.v, pool.k_scale, pool.v_scale = self._jit(
-                    params, pool.k, pool.v, pool.k_scale, pool.v_scale,
-                    *host)
-            else:
-                out, pool.k, pool.v = self._jit(params, pool.k, pool.v,
-                                                *host)
-        return np.asarray(out, np.float32)
 
     def bind(self, page_row: np.ndarray) -> "BoundPagedPrefill":
         return BoundPagedPrefill(self, np.asarray(page_row))

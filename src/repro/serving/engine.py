@@ -86,6 +86,7 @@ from repro.serving.scheduler import (
 )
 from repro.serving.sessions import SessionHandle, SessionStore
 from repro.serving.sharding import ServingSharding
+from repro.tracing import span, step_span
 
 
 @dataclasses.dataclass
@@ -216,6 +217,7 @@ class MPICEngine:
         self.frozen: List[Request] = []     # FROZEN via sessions.freeze()
         self._prefill_tasks: Dict[int, ChunkedPrefillTask] = {}
         self._rngs: Dict[str, np.random.Generator] = {}
+        self._steps = 0             # step number of the profiler's marker
 
         self._use_paged = self.cfg.paged and model.supports_paged_decode()
         if self.cfg.pool_dtype == "int8" and not self._use_paged:
@@ -378,24 +380,27 @@ class MPICEngine:
                 else contextlib.nullcontext())
 
     def step(self) -> None:
-        # crash injection runs BEFORE any per-request work: an injected
-        # replica failure must leave the engine state exactly as the last
-        # completed step did, so the cluster's failover drain sees a clean
-        # snapshot and no individual request gets blamed
-        if self.faults is not None:
-            rule = self.faults.check("engine.step",
-                                     f"replica{self.replica_id}")
-            if rule is not None and rule.kind == "crash":
-                raise ReplicaCrash(
-                    f"injected crash on replica {self.replica_id} "
-                    f"({rule.describe()})")
-        with self._shard_ctx():
-            self._reap_deadlines()
-            self._advance_prefills()
-            self._admit()
-            self._decode()
-        if self.cfg.freeze_idle_s > 0:
-            self.sessions.sweep_idle(self.cfg.freeze_idle_s)
+        self._steps += 1
+        with step_span(self._steps):
+            # crash injection runs BEFORE any per-request work: an
+            # injected replica failure must leave the engine state exactly
+            # as the last completed step did, so the cluster's failover
+            # drain sees a clean snapshot and no individual request gets
+            # blamed
+            if self.faults is not None:
+                rule = self.faults.check("engine.step",
+                                         f"replica{self.replica_id}")
+                if rule is not None and rule.kind == "crash":
+                    raise ReplicaCrash(
+                        f"injected crash on replica {self.replica_id} "
+                        f"({rule.describe()})")
+            with self._shard_ctx():
+                self._reap_deadlines()
+                self._advance_prefills()
+                self._admit()
+                self._decode()
+            if self.cfg.freeze_idle_s > 0:
+                self.sessions.sweep_idle(self.cfg.freeze_idle_s)
 
     # -- session store delegates (serving/sessions.py) ---------------------
     def freeze(self, req_id: str, *, spool: bool = False) -> SessionHandle:
@@ -458,7 +463,8 @@ class MPICEngine:
                     handle.release()
                 self._expire(req)
                 continue
-            self._begin_prefill(req, slot, handle)
+            with span("mpic.admit", req=req.req_id):
+                self._begin_prefill(req, slot, handle)
             admitted += 1
 
     # -- admission ------------------------------------------------------
@@ -686,8 +692,9 @@ class MPICEngine:
         req.linked_media = [seg.media_id
                             for _, seg in req.prompt.media_segments()]
 
-        first_tok = self._select_token(
-            req, np.asarray(result.first_logits, np.float32))
+        with span("mpic.sample"):
+            first_tok = self._select_token(
+                req, np.asarray(result.first_logits, np.float32))
         req.output_tokens.append(first_tok)
         req.t_first_token = time.perf_counter()
         req.cur_len = req.prompt.total_len
@@ -849,27 +856,30 @@ class MPICEngine:
                 if r is not None and r.state is State.RUNNING]
         if not live:
             return
-        if self._use_paged:
-            live, logits = self._decode_paged_step(live)
-        else:
-            logits = self._decode_dense_step(live)
-        for r in live:
-            nxt = self._select_token(r, logits[r.slot])
-            r.output_tokens.append(nxt)
-            r.cur_len += 1
-            if (r.freeze_after is not None
-                    and len(r.output_tokens) >= r.freeze_after
-                    and self._use_paged):
-                # deterministic mid-decode freeze point (fleet resume
-                # smoke): snapshot NOW, before this token is fed — the
-                # thawed session re-emits it, so resume parity composes as
-                # frozen[:-1] + thawed.  Spooled immediately: the point of
-                # an automated freeze is surviving whatever comes next.
-                self.sessions.freeze(r.req_id, spool=True)
-                continue
-            if len(r.output_tokens) >= r.max_new_tokens or \
-                    r.cur_len + 1 >= self.cfg.max_seq_len:
-                self._finish(r)
+        with span("mpic.decode"):
+            if self._use_paged:
+                live, logits = self._decode_paged_step(live)
+            else:
+                logits = self._decode_dense_step(live)
+            with span("mpic.decode.sample"):
+                for r in live:
+                    nxt = self._select_token(r, logits[r.slot])
+                    r.output_tokens.append(nxt)
+                    r.cur_len += 1
+                    if (r.freeze_after is not None
+                            and len(r.output_tokens) >= r.freeze_after
+                            and self._use_paged):
+                        # deterministic mid-decode freeze point (fleet
+                        # resume smoke): snapshot NOW, before this token is
+                        # fed — the thawed session re-emits it, so resume
+                        # parity composes as frozen[:-1] + thawed.  Spooled
+                        # immediately: the point of an automated freeze is
+                        # surviving whatever comes next.
+                        self.sessions.freeze(r.req_id, spool=True)
+                        continue
+                    if len(r.output_tokens) >= r.max_new_tokens or \
+                            r.cur_len + 1 >= self.cfg.max_seq_len:
+                        self._finish(r)
 
     def _decode_dense_step(self, live: List[Request]) -> np.ndarray:
         B = self.cfg.decode_slots
@@ -888,20 +898,23 @@ class MPICEngine:
     def _decode_paged_step(self, live: List[Request]):
         """One donated decode step over the page pool for all live slots
         (operands from :meth:`decode_operands`)."""
-        live, ops = self.decode_operands(live)
+        with span("mpic.decode.operands"):
+            live, ops = self.decode_operands(live)
         if not live:
             return live, None
         with self.scheduler.compute_window():
             pool = self.pool
-            if pool.quantized:
-                (logits, pool.k, pool.v,
-                 pool.k_scale, pool.v_scale) = self._decode_jit(
-                    self.params, pool.k, pool.v, pool.k_scale, pool.v_scale,
-                    *ops)
-            else:
-                logits, pool.k, pool.v = self._decode_jit(
-                    self.params, pool.k, pool.v, *ops)
-            logits = np.asarray(logits, np.float32)
+            with span("mpic.decode.dispatch"):
+                if pool.quantized:
+                    (logits, pool.k, pool.v,
+                     pool.k_scale, pool.v_scale) = self._decode_jit(
+                        self.params, pool.k, pool.v, pool.k_scale,
+                        pool.v_scale, *ops)
+                else:
+                    logits, pool.k, pool.v = self._decode_jit(
+                        self.params, pool.k, pool.v, *ops)
+            with span("mpic.decode.fetch"):
+                logits = np.asarray(logits, np.float32)
         return live, logits
 
     def decode_operands(self, live: List[Request]):
