@@ -8,8 +8,10 @@ caches and the paged decode-attention Pallas kernel.
 Every pool write is a donated jit, so the engine reassigns ``pool.k/pool.v``
 from the outputs and XLA updates the (aliased) buffers in place:
 :func:`scatter_tokens` (dense-prefill splice-in), :func:`pool_link` (the
-linker's ``link_paged`` placement and the engine's MRAG link), and the
-per-layer new-token scatters inside the donated decode/prefill steps
+linker's ``link_paged`` placement and the engine's MRAG link: one donated
+scatter per media segment, fed the library entry's own K/V at the
+segment's exact length, with no padding), and the per-layer new-token
+scatters inside the donated decode/prefill steps
 (``models/transformer.decode_paged`` / ``selective_prefill_paged``).
 Steady-state serving never copies the pool.
 
@@ -214,7 +216,7 @@ adopt_pages_q = functools.partial(
 
 def _bucket_pow2(n: int) -> int:
     """Next power of two ≥ n — bounds the trace count of the per-free
-    scale-reset jit the same way core.linker buckets placement runs."""
+    scale-reset jit the same way core.linker buckets prefill selections."""
     return 1 << max(n - 1, 0).bit_length()
 
 

@@ -11,7 +11,9 @@ Two targets: :func:`link_prompt` builds a dense per-request blended cache
 (the baselines' path, and the fallback when no page pool exists);
 :func:`link_paged` relocates the same segments straight into a
 :class:`~repro.cache.paged.PagedKVPool`'s reserved pages with one donated
-scatter — the serving engine's zero-copy prefill path.
+scatter per segment, each fed the library entry's own K/V at the
+segment's exact length (no concatenation, no padding) — the serving
+engine's zero-copy prefill path.
 """
 from __future__ import annotations
 
@@ -201,8 +203,9 @@ def link_prompt(model: Model, prompt: Prompt, library, selection: np.ndarray,
 def bucket(n: int, lo: int = 8) -> int:
     """Next power of two ≥ max(n, lo) — bounds distinct jit shapes to
     O(log max_seq_len) like the engine's page-table bucketing.  Shared by
-    the link scatter and the prefill step (``core/paged_prefill``) so the
-    two stages' compile-cache behavior cannot drift apart."""
+    the int8 direct link scatter and the prefill step
+    (``core/paged_prefill``) so their compile-cache behavior cannot drift
+    apart."""
     b = max(lo, 1)
     while b < n:
         b *= 2
@@ -214,18 +217,26 @@ def link_paged(model: Model, prompt: Prompt, library,
                scratch_page: int, entries=None) -> PagedLinkResult:
     """Link a prompt's reused segments DIRECTLY into reserved pool pages.
 
-    The paged twin of :func:`link_prompt`: placed segments are relinked with
-    one batched ``rope_relink`` and scattered into the request's pages by
-    the donated :func:`repro.cache.paged.pool_link` — no dense
-    ``(L, kv_len, H, D)`` blended cache is ever materialized, and nothing
-    needs splicing after the prefill.  Selected slots are NOT zeroed (the
-    dense path's dummy cache): the paged selective prefill scatters fresh
-    K/V into them before each layer's attention reads the pool, so stale
-    bytes there are never observed.
+    The paged twin of :func:`link_prompt`: each placed segment is
+    relinked and scattered into the request's pages by its own call of
+    the pool's donated link write (``PagedKVPool.link_write``), whose K/V
+    operands are the library entry's own arrays — no dense
+    ``(L, kv_len, H, D)`` blended cache and no host concatenation of the
+    segments is ever materialized, and nothing needs splicing after the
+    prefill.  Selected slots are NOT zeroed (the dense path's dummy cache):
+    the paged selective prefill scatters fresh K/V into them before each
+    layer's attention reads the pool, so stale bytes there are never
+    observed.
 
-    The placed-token axis of the scatter is padded to a power-of-two bucket
-    (pad rows land on ``scratch_page``), so steady-state traffic with
-    varying media footprints reuses a warm ``pool_link`` compile cache.
+    A scatter carries exactly one segment's tokens, unpadded, so the link
+    compiles once per distinct media length: prompts that differ in image
+    count or order reuse a warm compile cache.  On an int8 pool each write
+    quantizes through the running page scales, so a page two segments
+    share is requantized when the later one raises its scale, as after
+    any later write to a page.  Only the int8 direct link
+    (every entry int8-resident on an int8 pool) still packs all segments
+    into one scatter padded to a power-of-two bucket, its pad rows landing
+    on ``scratch_page``.
     """
     cfg = model.cfg
     total = prompt.total_len
@@ -240,14 +251,12 @@ def link_paged(model: Model, prompt: Prompt, library,
             direct, host = _pack_placed(pool, placed, page_row,
                                         scratch_page)
         with span("mpic.link.upload"):
-            ops = [jnp.asarray(a) for a in host]
+            calls = [[jnp.asarray(a) for a in ops] for ops in host]
         relink = bool(cfg.rope_theta) and not cfg.learned_pos_emb
+        write = pool.link_write_q8 if direct else pool.link_write
         with span("mpic.link.write"):
-            if direct:
-                pool.link_write_q8(*ops, theta=cfg.rope_theta,
-                                   relink=relink)
-            else:
-                pool.link_write(*ops, theta=cfg.rope_theta, relink=relink)
+            for ops in calls:
+                write(*ops, theta=cfg.rope_theta, relink=relink)
         if direct and library is not None:
             library.note_direct_link(len(placed))
 
@@ -267,11 +276,37 @@ def link_paged(model: Model, prompt: Prompt, library,
 
 
 def _pack_placed(pool, placed, page_row, scratch_page):
-    """Host operands of the pool scatter that links ``placed`` segments:
-    ``(direct, arrays)``, where ``direct`` picks ``pool.link_write_q8``
-    over ``pool.link_write`` and ``arrays`` are its positional operands
-    (pages, offsets, K/V or their int8 rows and scales, delta)."""
+    """Host operands of the pool writes that link ``placed`` segments:
+    ``(direct, calls)``, where ``direct`` picks ``pool.link_write_q8``
+    over ``pool.link_write`` and each of ``calls`` is one write's
+    positional operands (pages, offsets, K/V or their int8 rows and
+    scales, delta).
+
+    The fp link writes once per segment, and its K/V operands are the
+    entry's own arrays: only the (n,) index operands are built here, so
+    no host array larger than one entry's K or V is allocated."""
     ps = pool.cfg.page_size
+    page_row = np.asarray(page_row)
+    direct = (getattr(pool, "quantized", False)
+              and all(getattr(e, "payload", None) is not None
+                      and e.payload.qk is not None
+                      and e.payload.qk.block_tokens
+                      == e.payload.qv.block_tokens
+                      for _, e, _ in placed))
+    if not direct:
+        calls = []
+        for off, e, n in placed:
+            t = np.arange(off, off + n)
+            calls.append((page_row[t // ps], (t % ps).astype(np.int32),
+                          e.k, e.v, np.full(n, off, np.int32)))
+        return False, calls
+    # spool→pool zero copy: every placed entry is int8-resident, so its
+    # bytes rescale straight onto the pool's page grid inside one donated
+    # jit — no dequantize→requantize fp round trip and no fp copy of any
+    # block.  The placed-token axis is padded to a power-of-two bucket
+    # (pad rows land on ``scratch_page``).  Scale rows from all segments
+    # stack into one (L, rows, H, Dh) operand; ``seg_ids`` maps each
+    # placed token to its row (whole-seq or block_tokens granular).
     idx = np.concatenate([np.arange(off, off + n) for off, _, n in placed])
     delta = np.concatenate([np.full(n, off, np.int32)
                             for off, _, n in placed])
@@ -282,59 +317,36 @@ def _pack_placed(pool, placed, page_row, scratch_page):
         delta = np.concatenate([delta, np.zeros(pad, np.int32)])
     pages = np.full((b,), scratch_page, np.int32)
     offs = np.zeros((b,), np.int32)
-    pages[:n_placed] = np.asarray(page_row)[idx // ps]
+    pages[:n_placed] = page_row[idx // ps]
     offs[:n_placed] = idx % ps
-    direct = (getattr(pool, "quantized", False)
-              and all(getattr(e, "payload", None) is not None
-                      and e.payload.qk is not None
-                      and e.payload.qk.block_tokens
-                      == e.payload.qv.block_tokens
-                      for _, e, _ in placed))
-    if direct:
-        # spool→pool zero copy: every placed entry is int8-resident, so
-        # its bytes rescale straight onto the pool's page grid inside
-        # one donated jit — no dequantize→requantize fp round trip and
-        # no fp copy of any block.  Scale rows from all segments stack
-        # into one (L, rows, H, Dh) operand; ``seg_ids`` maps each
-        # placed token to its row (whole-seq or block_tokens granular).
-        qks, qvs, ksr, vsr, sids = [], [], [], [], []
-        base = 0
-        for off, e, n in placed:
-            qk, qv = e.payload.qk, e.payload.qv
-            qks.append(qk.q[:, :n])
-            qvs.append(qv.q[:, :n])
-            ksr.append(qk.scale)
-            vsr.append(qv.scale)
-            sids.append(base + scale_row_ids(n, qk))
-            base += qk.scale.shape[1]
-        qk_cat = np.concatenate(qks, axis=1)
-        qv_cat = np.concatenate(qvs, axis=1)
-        sid = np.concatenate(sids)
-        if pad > 0:
-            z = np.zeros(qk_cat.shape[:1] + (pad,) + qk_cat.shape[2:],
-                         np.int8)
-            qk_cat = np.concatenate([qk_cat, z], axis=1)
-            qv_cat = np.concatenate([qv_cat, z], axis=1)
-            sid = np.concatenate([sid, np.zeros(pad, np.int32)])
-        ks_cat = np.concatenate(ksr, axis=1)
-        vs_cat = np.concatenate(vsr, axis=1)
-        # bucket the scale-row axis too (pad rows are never referenced)
-        rpad = bucket(base, 1) - base
-        if rpad > 0:
-            zr = np.ones(ks_cat.shape[:1] + (rpad,) + ks_cat.shape[2:],
-                         np.float32)
-            ks_cat = np.concatenate([ks_cat, zr], axis=1)
-            vs_cat = np.concatenate([vs_cat, zr], axis=1)
-        return True, (pages, offs, qk_cat, ks_cat, qv_cat, vs_cat, sid,
-                      delta)
-    k_cat = np.concatenate([np.asarray(e.k) for _, e, _ in placed], axis=1)
-    v_cat = np.concatenate([np.asarray(e.v) for _, e, _ in placed], axis=1)
+    qks, qvs, ksr, vsr, sids = [], [], [], [], []
+    base = 0
+    for off, e, n in placed:
+        qk, qv = e.payload.qk, e.payload.qv
+        qks.append(qk.q[:, :n])
+        qvs.append(qv.q[:, :n])
+        ksr.append(qk.scale)
+        vsr.append(qv.scale)
+        sids.append(base + scale_row_ids(n, qk))
+        base += qk.scale.shape[1]
+    qk_cat = np.concatenate(qks, axis=1)
+    qv_cat = np.concatenate(qvs, axis=1)
+    sid = np.concatenate(sids)
     if pad > 0:
-        zeros = np.zeros(k_cat.shape[:1] + (pad,) + k_cat.shape[2:],
-                         k_cat.dtype)
-        k_cat = np.concatenate([k_cat, zeros], axis=1)
-        v_cat = np.concatenate([v_cat, zeros], axis=1)
-    return False, (pages, offs, k_cat, v_cat, delta)
+        z = np.zeros(qk_cat.shape[:1] + (pad,) + qk_cat.shape[2:], np.int8)
+        qk_cat = np.concatenate([qk_cat, z], axis=1)
+        qv_cat = np.concatenate([qv_cat, z], axis=1)
+        sid = np.concatenate([sid, np.zeros(pad, np.int32)])
+    ks_cat = np.concatenate(ksr, axis=1)
+    vs_cat = np.concatenate(vsr, axis=1)
+    # bucket the scale-row axis too (pad rows are never referenced)
+    rpad = bucket(base, 1) - base
+    if rpad > 0:
+        zr = np.ones(ks_cat.shape[:1] + (rpad,) + ks_cat.shape[2:],
+                     np.float32)
+        ks_cat = np.concatenate([ks_cat, zr], axis=1)
+        vs_cat = np.concatenate([vs_cat, zr], axis=1)
+    return True, [(pages, offs, qk_cat, ks_cat, qv_cat, vs_cat, sid, delta)]
 
 
 def reselect_paged(model: Model, prompt: Prompt, link: PagedLinkResult,

@@ -14,9 +14,9 @@ Spans (nesting by indentation)::
     mpic.step                  one engine step (the profiler's step marker)
       mpic.admit  req=<id>     one admitted request, link through first token
         mpic.link.gather       library entries of the prompt's media
-        mpic.link.pack         index, delta and page arrays; K/V concatenated
-        mpic.link.upload       the packed arrays to the device
-        mpic.link.write        the pool scatter's dispatch
+        mpic.link.pack         each segment's page, offset and delta arrays
+        mpic.link.upload       each entry's K/V and those arrays to the device
+        mpic.link.write        the dispatch of one pool scatter per segment
         mpic.prefill.pack      selected-token inputs, buckets, page table
         mpic.prefill.dispatch  the selective prefill's dispatch
         mpic.prefill.fetch     first-token logits back to the host
