@@ -1,8 +1,9 @@
 """Whether what the timed path served is correct.
 
 After the window, a sample of the finished requests, drawn from the seed
-and holding the longest, is run through the plain reference
-(``bench/reference.py``) over each prompt and its served tokens.  The
+and holding the longest, is run through the plain reference of the
+configuration's architecture file (``bench/reference.py`` for the dense
+block) over each prompt and its served tokens.  The
 number compared is the widest gap by which a served token's logit lies
 below the reference's best logit at that step; every token is greedy.
 The limit is per cell, in ``bench/limits/<cell>.json``, with the readings
@@ -15,7 +16,8 @@ from typing import Dict, List
 
 import numpy as np
 
-from bench.reference import Arch, Item, Reference, served_gaps
+from bench import spec
+from bench.reference import Item, served_gaps
 from bench.weights import image_embeds
 
 
@@ -48,14 +50,14 @@ def pick(finished: List, seed: int, target: int, cap: int = 24) -> List:
     return chosen
 
 
-def reference_for(conf: dict, params, seed: int, k: int,
-                  lowp: bool = False) -> Reference:
-    arch = Arch.from_config(conf)
-    n_img = conf["assumed"]["image_tokens"]
-    return Reference(params, arch,
-                     lambda idx: image_embeds(seed, idx, n_img,
-                                              arch.d_model),
-                     k=k, lowp=lowp)
+def reference_for(cell, params, seed: int, k: int, lowp: bool = False):
+    """The ``Reference`` of the cell's architecture file over ``params``."""
+    ref = spec.arch(cell)
+    conf = cell.config
+    n_img, width = conf["assumed"]["image_tokens"], conf["hidden_size"]
+    return ref.Reference(params, ref.Arch.from_config(conf),
+                         lambda idx: image_embeds(seed, idx, n_img, width),
+                         k=k, lowp=lowp)
 
 
 def verdict(cell, gaps: List[np.ndarray], who: str = "") -> Dict:
@@ -88,8 +90,8 @@ def compare(cell, params, finished: List, seed: int,
     served tokens, the token that the float8 reference puts first."""
     sample = pick(finished, seed, int(cell.traffic["check"]["served_tokens"]))
     k = int(cell.traffic["mpic_k"])
-    ref = reference_for(cell.config, params, seed, k)
-    low = reference_for(cell.config, params, seed, k, lowp=True) \
+    ref = reference_for(cell, params, seed, k)
+    low = reference_for(cell, params, seed, k, lowp=True) \
         if control else None
     prog, ctrl = [], []
     for r in sample:

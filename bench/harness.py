@@ -11,7 +11,6 @@ from __future__ import annotations
 import collections
 import dataclasses
 import gc
-import importlib.util
 import os
 import shutil
 import sys
@@ -126,11 +125,7 @@ def reader_path(name: str, bench=None):
 
 
 def load_reader(name: str):
-    path = reader_path(name)
-    sp = importlib.util.spec_from_file_location(f"bench_metric_{name}", path)
-    mod = importlib.util.module_from_spec(sp)
-    sp.loader.exec_module(mod)
-    return mod.read
+    return spec.load_module(reader_path(name)).read
 
 
 class Run:
@@ -140,6 +135,8 @@ class Run:
                  traced: bool):
         self.cell, self.seed, self.seconds = cell, seed, seconds
         self.traced = traced
+        self.conf = cell.config       # the configuration file, as written
+        self.arch = spec.arch(cell)   # its architecture file's module
         self.recs: List[Rec] = []
         self.steps: List[StepRec] = []
         self.traced_steps: List[StepRec] = []
@@ -173,9 +170,10 @@ def build(cell: spec.Cell, seed: int):
     from repro.models import build_model
     from repro.serving import EngineConfig, MPICEngine
 
-    cfg = spec.model_config(cell.config)
+    arch = spec.arch(cell)
+    cfg = spec.model_config(cell.config, getattr(arch, "PROGRAM", None))
     model = build_model(cfg)
-    params = make_params(model, seed)
+    params = make_params(model, seed, getattr(arch, "STD", None))
     jax.block_until_ready(params)
     serving = cell.config["serving"]
     ps = int(serving["page_size"])
@@ -469,8 +467,7 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, traced: bool,
     pauses.close()
     run.setup_s = run.t_traffic0 - t_proc0
     log_stalls(run, pauses)
-    run.cfg, run.peak = srv.cfg, peaks(info["kind"]) \
-        if info["platform"] == "tpu" else None
+    run.peak = peaks(info["kind"]) if info["platform"] == "tpu" else None
     metrics = {}
     for m in (cell.per_layer if traced else cell.end_to_end):
         v = load_reader(m["name"])(run)

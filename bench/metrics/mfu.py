@@ -8,7 +8,7 @@ def read(run):
     t = run.trace
     if t is None or run.peak is None or t.window_s <= 0:
         return None
-    f = sum(step_flops(run.cfg, s) for s in t.steps)
+    f = sum(step_flops(run, s) for s in t.steps)
     if f <= 0:
         return None
     return 100.0 * f / (t.window_s * run.peak["flops_bf16"])

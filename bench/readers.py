@@ -41,14 +41,16 @@ def prefill_is_mpic(rec) -> bool:
     return rec.req.prefill_stats.get("n_recomputed") == len(rec.selected)
 
 
-def step_flops(cfg, step) -> float:
+def step_flops(run, step) -> float:
     """Model operations of every token a step computed: decode tokens with
-    the LM head, recomputed prefill tokens, and one head row per prefill."""
-    f = sum(flops.token_flops(cfg, c, head=True) for c in step.decode_ctx)
+    the LM head, recomputed prefill tokens, and one head row per prefill
+    (counted by the cell's architecture file)."""
+    w, conf = run.arch, run.conf
+    f = sum(w.token_flops(conf, c, head=True) for c in step.decode_ctx)
     for rec in step.prefills:
-        f += sum(flops.token_flops(cfg, p + 1, head=False)
+        f += sum(w.token_flops(conf, p + 1, head=False)
                  for p in rec.selected)
-        f += 2 * flops.head_params(cfg)
+        f += 2 * w.head_params(conf)
     return f
 
 

@@ -139,3 +139,17 @@ def test_split_metric_reads_its_base_reader():
     assert harness.reader_path("prefill_ms_p50.sat").name == \
         "prefill_ms_p50.py"
     assert harness.reader_path("mfu.prefill").name == "mfu.prefill.py"
+
+
+def test_text_only_cell_tiny(tmp_path):
+    """The chat-text shape: unshared text prompts, no library image, so
+    the link is bypassed and every prompt token is recomputed."""
+    from bench import harness, spec
+    import time
+    root = make_root(tmp_path, traffic="tiny-text", cell="tiny.text")
+    cell = spec.load_cell("tiny.text", root)
+    out = harness.run_cell(cell, 2 ** 33 + 3, 3.0, False, time.perf_counter())
+    assert out["correct"] is True, out["checks"]
+    assert out["attempted"] > 0 and out["failed"] == 0
+    for name in ("ttft_p50_ms", "itl_p95_ms", "setup_s"):
+        assert out["metrics"][name]["value"] > 0, name

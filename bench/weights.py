@@ -9,6 +9,7 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -28,17 +29,19 @@ def _leaf_name(path) -> str:
     return "/".join(str(getattr(p, "key", p)) for p in path)
 
 
-def _fill(key, shapes):
+def _fill(key, shapes, std_of):
     leaves, tree = jax.tree_util.tree_flatten_with_path(shapes)
     out = []
     for i, (path, s) in enumerate(leaves):
         name = _leaf_name(path)
         last = name.split("/")[-1]
         k = jax.random.fold_in(key, i)
-        if last == "scale":                         # RMSNorm scales
+        if name in std_of:                          # named by the arch file
+            std = std_of[name]
+        elif last == "scale":                       # RMSNorm scales
             out.append(jnp.ones(s.shape, s.dtype))
             continue
-        if last in ("bq", "bk", "bv"):
+        elif last in ("bq", "bk", "bv"):
             std = BIAS_STD
         elif name == "embed":
             std = EMBED_STD
@@ -49,10 +52,22 @@ def _fill(key, shapes):
     return jax.tree_util.tree_unflatten(tree, out)
 
 
-def make_params(model, seed: int):
-    """The program's parameter tree, filled from ``seed`` on the device."""
+def make_params(model, seed: int, std: Optional[Dict[str, float]] = None):
+    """The program's parameter tree, filled from ``seed`` on the device.
+
+    ``std`` (the ``STD`` of a configuration's architecture file) gives
+    the standard deviation of named leaves, such as those the rules above
+    do not cover; a name that is no leaf of the tree is an error."""
     shapes = jax.eval_shape(model.init, jax.random.key(0))
-    return jax.jit(functools.partial(_fill, shapes=shapes))(seed_key(seed, 0))
+    std = dict(std or {})
+    names = {_leaf_name(p)
+             for p, _ in jax.tree_util.tree_flatten_with_path(shapes)[0]}
+    unknown = sorted(set(std) - names)
+    if unknown:
+        raise ValueError(f"STD names no parameter: "
+                         f"{', '.join(unknown)}; leaves: {sorted(names)}")
+    return jax.jit(functools.partial(_fill, shapes=shapes, std_of=std))(
+        seed_key(seed, 0))
 
 
 @functools.lru_cache(maxsize=None)
